@@ -314,6 +314,10 @@ def _bench_serve_tp(small: bool) -> list[Row]:
 
     if os.environ.get("BENCH_TP", "1") == "0":
         return []
+    if jax.default_backend() == "tpu":
+        # the child runs on forced host CPU devices: its rows would be
+        # host-CPU numbers in a TPU run, and this process holds the chip
+        return []
     env = dict(os.environ,
                XLA_FLAGS="--xla_force_host_platform_device_count=8",
                JAX_PLATFORMS="cpu")

@@ -1,7 +1,7 @@
 """Jaxpr walker: flatten a traced serving graph into scope-tagged,
 provenance-annotated equation records.
 
-The walker recurses through call primitives (``pjit``, ``scan``,
+The walker recurses through call primitives (``jit``, ``scan``,
 ``while``, ``cond``, ``custom_*``, ``remat``) and produces one
 :class:`EqnRecord` per equation at every nesting depth, carrying
 
@@ -18,8 +18,7 @@ The walker recurses through call primitives (``pjit``, ``scan``,
     "do this scatter's *indices* depend on the active mask?" statically.
 
 The walker deliberately avoids importing jax internals: vars, literals
-and (closed) jaxprs are duck-typed, so it tracks jaxlib across minor
-versions.
+and (closed) jaxprs are duck-typed.
 """
 from __future__ import annotations
 
@@ -33,9 +32,7 @@ EMPTY: Frozenset[int] = frozenset()
 # Call primitives whose subjaxpr invars map 1:1 onto the equation's
 # invars (no carry/const split).
 _ONE_TO_ONE_CALLS = {
-    "pjit", "closed_call", "core_call", "xla_call", "named_call",
-    "remat2", "checkpoint", "custom_jvp_call", "custom_vjp_call",
-    "custom_jvp_call_jaxpr", "custom_vjp_call_jaxpr",
+    "jit", "closed_call", "remat2", "custom_jvp_call", "custom_vjp_call",
 }
 
 

@@ -22,7 +22,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 _EPS = 1e-12
@@ -55,8 +54,8 @@ def compressed_psum(x: jax.Array, mesh: Mesh, axis: str) -> jax.Array:
 
     ndim = x.ndim
     spec = P(axis, *([None] * (ndim - 1)))
-    fn = shard_map(body, mesh=mesh, in_specs=(spec,), out_specs=spec,
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec,), out_specs=spec,
+                       check_vma=False)
     return fn(x)
 
 

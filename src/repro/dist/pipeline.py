@@ -19,7 +19,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -74,8 +73,8 @@ def pipelined_forward(mesh: Mesh, stage_fn: Callable[..., jax.Array],
     nd = x.ndim
     w_specs = jax.tree_util.tree_map(
         lambda l: P("pod", *([None] * (l.ndim - 1))), w)
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(*([None] * nd)), w_specs),
-                   out_specs=P(*([None] * nd)),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(*([None] * nd)), w_specs),
+                       out_specs=P(*([None] * nd)),
+                       check_vma=False)
     return fn(x, w).astype(x.dtype)

@@ -27,8 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import tpu_compiler_params
-
 
 def _bitslice_mvm_kernel(x_ref, w_ref, o_ref, acc_ref, *, n_slices: int,
                          bits_per_slice: int, k_steps: int):
@@ -99,12 +97,12 @@ def bitslice_mvm_pallas(x: jax.Array, w_planes: jax.Array, *,
                         bits_per_slice: int,
                         block_m: int = 128, block_n: int = 128,
                         block_k: int = 128,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: bool) -> jax.Array:
     """x: [M, K] int8; w_planes: [S, K, N] int8 -> [M, N] int32.
 
     M, K, N must be multiples of the block sizes (ops.py pads).
-    ``interpret=True`` runs the kernel body on CPU for validation; on a
-    real TPU pass ``interpret=False``.
+    ``interpret`` runs the kernel body through the Pallas interpreter
+    (any backend) instead of compiling it for the TPU.
     """
     s, k, n = w_planes.shape
     m = x.shape[0]
@@ -130,9 +128,10 @@ def bitslice_mvm_pallas(x: jax.Array, w_planes: jax.Array, *,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="bitslice_mvm",
     )(x, w_planes)
 
 
@@ -141,7 +140,7 @@ def bitslice_mvm_scaled_pallas(x: jax.Array, w_planes: jax.Array,
                                bits_per_slice: int,
                                block_m: int = 128, block_n: int = 128,
                                block_k: int = 128,
-                               interpret: bool = True) -> jax.Array:
+                               interpret: bool) -> jax.Array:
     """x: [M, K] int8; w_planes: [S, K, N] int8; row_scale: [M, 1] f32
     -> [M, N] f32 == (recombined int MVM).astype(f32) * row_scale.
 
@@ -170,7 +169,8 @@ def bitslice_mvm_scaled_pallas(x: jax.Array, w_planes: jax.Array,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="bitslice_mvm_scaled",
     )(x, w_planes, row_scale)

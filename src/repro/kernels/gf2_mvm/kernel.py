@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import tpu_compiler_params
-
 
 def _gf2_mvm_kernel(x_ref, a_ref, o_ref, acc_ref, *, k_steps: int):
     @pl.when(pl.program_id(2) == 0)
@@ -40,7 +38,7 @@ def _gf2_mvm_kernel(x_ref, a_ref, o_ref, acc_ref, *, k_steps: int):
 
 def gf2_mvm_pallas(x: jax.Array, a: jax.Array, *, block_m: int = 128,
                    block_n: int = 128, block_k: int = 128,
-                   interpret: bool = True) -> jax.Array:
+                   interpret: bool) -> jax.Array:
     """x: [M, K] int8 {0,1}; a: [K, N] int8 {0,1} -> [M, N] int8 {0,1}."""
     m, k = x.shape
     k2, n = a.shape
@@ -59,7 +57,8 @@ def gf2_mvm_pallas(x: jax.Array, a: jax.Array, *, block_m: int = 128,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int8),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="gf2_mvm",
     )(x, a)

@@ -54,5 +54,6 @@ def paged_attention_ref(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
     m = jnp.max(scores, axis=-1, keepdims=True)
     e = jnp.exp(scores - jax.lax.stop_gradient(m))
     probs = e / jnp.sum(e, axis=-1, keepdims=True)
-    out = jnp.einsum("bksgt,btkd->bskgd", probs.astype(v_all.dtype), v_all)
-    return k_pool, v_pool, out
+    out = jnp.einsum("bksgt,btkd->bskgd", probs.astype(v_all.dtype), v_all,
+                     preferred_element_type=jnp.float32)
+    return k_pool, v_pool, out.astype(v_all.dtype)

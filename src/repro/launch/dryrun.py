@@ -168,7 +168,7 @@ def _probe_costs(cfg: ModelConfig, shape: ShapeConfig, mesh,
             enc = max(1, k * cfg.encoder_layers // n_groups)
             pcfg = pcfg.replace(encoder_layers=enc)
         compiled, _ = _lower_one(pcfg, shape, mesh, pscfg, tcfg)
-        ca = rl.cost_analysis_dict(compiled)
+        ca = compiled.cost_analysis()
         coll = rl.collective_bytes_from_hlo(compiled.as_text())
         cbytes = sum(coll.values()) + coll.get("all-reduce", 0)
         return (float(ca.get("flops", 0.0)),
@@ -228,7 +228,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
     ma = compiled.memory_analysis()
     print(f"[{arch} x {shape_name} x {mesh_name} x {tag}] "
           f"memory_analysis: {ma}")
-    ca = rl.cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     print(f"[{arch} x {shape_name} x {mesh_name} x {tag}] cost_analysis: "
           f"flops={ca.get('flops', 0):.4g} "
           f"bytes={ca.get('bytes accessed', 0):.4g}")

@@ -13,13 +13,10 @@ import jax
 
 
 def _make_mesh(shape, axes) -> jax.sharding.Mesh:
-    # jax >= 0.5 takes axis_types (and needs Auto for with_sharding_
-    # constraint under explicit sharding); 0.4.x has no such kwarg
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    # Auto axes: the serving/training code places values with
+    # with_sharding_constraint, which explicit axes would refuse
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

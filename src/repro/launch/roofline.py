@@ -26,15 +26,6 @@ PEAK_FLOPS = 197e12          # bf16 / chip
 HBM_BW = 819e9               # B/s / chip
 LINK_BW = 50e9               # B/s / link
 
-
-def cost_analysis_dict(compiled) -> dict[str, float]:
-    """``compiled.cost_analysis()`` returns one dict on jax >= 0.5 but a
-    one-per-module list on 0.4.x; normalise to the dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
-
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2,
     "f16": 2, "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8, "f64": 8,
@@ -149,7 +140,7 @@ class RooflineReport:
 
 def from_compiled(compiled, *, arch: str, shape: str, mesh_name: str,
                   chips: int, model_flops: float) -> RooflineReport:
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     flops = float(ca.get("flops", 0.0))
     byt = float(ca.get("bytes accessed", 0.0))
     hlo = compiled.as_text()

@@ -27,6 +27,13 @@ shared read-only between requests, copy-on-write at the boundary):
 --kv-block-size 16 --chunked-prefill --prefix-cache
 --shared-prefix-len 32``
 
+Published width and depth on one TPU chip (weights initialised and
+packed in one program; the compiled Pallas kernels serve every step):
+``python -m repro.launch.serve --arch qwen2.5-3b --full-width
+--pum-mode int8 --kernel-backend pallas --batch-slots 8
+--kv-block-size 16 --chunked-prefill --requests 16 --min-prompt-len 64
+--prompt-len 512 --min-gen 32 --gen 32 --max-len 1024``
+
 Resilient front-end (PR 7: bounded admission queue, deadlines,
 backpressure, typed reject/expire outcomes; optional chaos injection):
 ``python -m repro.launch.serve --arch glm4-9b --batch-slots 4
@@ -42,19 +49,36 @@ import time
 import jax
 
 from repro import configs
-from repro.config import PUMConfig
+from repro.config import ModelConfig, PUMConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_tp_mesh
 from repro.models import lm
 from repro.serve import (ChaosPolicy, ContinuousBatchingScheduler,
                          ServeEngine, ServeFrontend, synthetic_workload)
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="glm4-9b")
+    ap.add_argument("--full-width", action="store_true",
+                    help="serve the published configuration "
+                         "(repro.configs.get) instead of its CPU-sized "
+                         "reduction")
     ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--prompt-len", type=int, default=16)
-    ap.add_argument("--gen", type=int, default=16)
+    ap.add_argument("--prompt-len", type=int, default=16,
+                    help="prompt length (static batch) or the longest "
+                         "synthetic prompt (--batch-slots)")
+    ap.add_argument("--min-prompt-len", type=int, default=1,
+                    help="shortest synthetic prompt for --batch-slots")
+    ap.add_argument("--gen", type=int, default=16,
+                    help="tokens to generate (static batch) or the "
+                         "largest synthetic token budget (--batch-slots)")
+    ap.add_argument("--min-gen", type=int, default=1,
+                    help="smallest synthetic token budget for "
+                         "--batch-slots")
+    ap.add_argument("--max-len", type=int, default=0,
+                    help="per-request KV window (default: prompt-len + "
+                         "gen + 1)")
     ap.add_argument("--pum-mode", default="bf16",
                     choices=["bf16", "int8", "pum"])
     ap.add_argument("--temperature", type=float, default=0.0)
@@ -74,7 +98,8 @@ def main():
                     help="trace length for --batch-slots "
                          "(default: 4x slots)")
     ap.add_argument("--seed", type=int, default=0,
-                    help="workload trace seed for --batch-slots")
+                    help="seed of the random weights and of the "
+                         "synthetic request trace")
     ap.add_argument("--kv-block-size", type=int, default=0,
                     help="page the KV cache into blocks of this many "
                          "tokens over one shared pool (0 = contiguous "
@@ -134,19 +159,42 @@ def main():
                          "of this many devices (1 = single device; on "
                          "CPU force devices with XLA_FLAGS="
                          "--xla_force_host_platform_device_count=N)")
-    args = ap.parse_args()
+    return ap
 
-    cfg = configs.get_reduced(args.arch)
+
+def served_config(args) -> ModelConfig:
+    """The model configuration ``args`` selects, in its serving mode."""
+    cfg = (configs.get if args.full_width else configs.get_reduced)(
+        args.arch)
     if args.pum_mode != "bf16":
         cfg = cfg.replace(pum=PUMConfig(mode=args.pum_mode))
-    params = lm.init_params(cfg, jax.random.PRNGKey(0))
+    return cfg
+
+
+def served_params(cfg: ModelConfig, args):
+    """Random weights from ``--seed``, packed at load for int8/pum (one
+    program, so the float32 tree never sits beside its packed copy)."""
+    key = jax.random.PRNGKey(args.seed)
+    if args.pum_mode != "bf16" and not args.no_prepack:
+        return lm.init_served_params(cfg, key)
+    return lm.init_params(cfg, key)
+
+
+def max_len(args) -> int:
+    return args.max_len or args.prompt_len + args.gen + 1
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
+    cfg = served_config(args)
+    params = served_params(cfg, args)
     mesh = make_tp_mesh(args.tp) if args.tp > 1 else None
 
     if args.batch_slots > 0:
         serve_continuous(cfg, params, args, mesh)
         return
-    eng = ServeEngine(cfg, params,
-                      max_len=args.prompt_len + args.gen + 1,
+    eng = ServeEngine(cfg, params, max_len=max_len(args),
                       prepack=not args.no_prepack,
                       use_scan=not args.loop,
                       mesh=mesh, kernel_backend=_kernel_backend(args))
@@ -172,26 +220,40 @@ def _kernel_backend(args):
     return None if args.kernel_backend == "auto" else args.kernel_backend
 
 
-def serve_continuous(cfg, params, args, mesh=None) -> None:
-    """Drive the slot-based scheduler over a synthetic arrival trace."""
-    n = args.requests or 4 * args.batch_slots
-    max_len = args.prompt_len + args.gen + 1
-    sched = ContinuousBatchingScheduler(
-        cfg, params, num_slots=args.batch_slots, max_len=max_len,
+def make_scheduler(cfg, params, args, mesh=None
+                   ) -> ContinuousBatchingScheduler:
+    """The continuous-batching scheduler ``args`` describes."""
+    return ContinuousBatchingScheduler(
+        cfg, params, num_slots=args.batch_slots, max_len=max_len(args),
         prepack=not args.no_prepack, kv_block_size=args.kv_block_size,
         num_kv_blocks=args.num_kv_blocks,
         chunked_prefill=args.chunked_prefill,
         prefix_cache=args.prefix_cache, mesh=mesh,
         kernel_backend=_kernel_backend(args),
         speculate_k=args.speculate_k)
-    if args.frontend:
-        serve_frontend(cfg, sched, args, n)
-        return
-    reqs = synthetic_workload(
-        n, cfg.vocab_size, max_prompt=args.prompt_len, max_new=args.gen,
-        mean_interarrival=0.0 if args.workload == "burst" else 2.0,
+
+
+def make_workload(cfg, args, **arrivals) -> list:
+    """The seeded synthetic trace ``args`` describes; ``arrivals``
+    overrides the arrival process (``poisson_rate`` for the front-end)."""
+    arrivals = arrivals or dict(
+        mean_interarrival=0.0 if args.workload == "burst" else 2.0)
+    return synthetic_workload(
+        args.requests or 4 * args.batch_slots, cfg.vocab_size,
+        max_prompt=args.prompt_len, min_prompt=args.min_prompt_len,
+        max_new=args.gen, min_new=args.min_gen,
         temperature_choices=(args.temperature,),
-        shared_prefix_len=args.shared_prefix_len, seed=args.seed)
+        shared_prefix_len=args.shared_prefix_len, seed=args.seed,
+        **arrivals)
+
+
+def serve_continuous(cfg, params, args, mesh=None) -> None:
+    """Drive the slot-based scheduler over a synthetic arrival trace."""
+    sched = make_scheduler(cfg, params, args, mesh)
+    if args.frontend:
+        serve_frontend(cfg, sched, args)
+        return
+    reqs = make_workload(cfg, args)
     t0 = time.perf_counter()
     out = sched.run(reqs)
     dt = time.perf_counter() - t0
@@ -220,7 +282,7 @@ def serve_continuous(cfg, params, args, mesh=None) -> None:
     print("sample:", (first.prompt + first.tokens)[:32])
 
 
-def serve_frontend(cfg, sched, args, n) -> None:
+def serve_frontend(cfg, sched, args) -> None:
     """Drive the resilient front-end over a (Poisson) arrival trace:
     overload comes back as typed outcomes, and the run ends with a
     metrics snapshot instead of a stack trace."""
@@ -230,11 +292,9 @@ def serve_frontend(cfg, sched, args, n) -> None:
         sched, clock=VirtualClock(), max_queue=args.max_queue,
         policy=args.policy, default_deadline_ms=args.deadline_ms,
         chaos=chaos if chaos is not None and chaos.enabled else None)
-    reqs = synthetic_workload(
-        n, cfg.vocab_size, max_prompt=args.prompt_len, max_new=args.gen,
-        poisson_rate=0.0 if args.workload == "burst" else 25.0,
-        temperature_choices=(args.temperature,),
-        shared_prefix_len=args.shared_prefix_len, seed=args.seed)
+    reqs = make_workload(
+        cfg, args,
+        poisson_rate=0.0 if args.workload == "burst" else 25.0)
     t0 = time.perf_counter()
     res = fe.results(fe.serve_trace(reqs))
     dt = time.perf_counter() - t0

@@ -11,10 +11,10 @@ from __future__ import annotations
 import argparse
 import json
 
-
 from repro import configs
 from repro.config import PUMConfig, ShardingConfig, TrainConfig
 from repro.ft import PreemptionHandler
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train.trainer import Trainer
 
 
@@ -36,6 +36,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = (configs.get_reduced if args.reduced else configs.get)(args.arch)
     if args.pum_mode != "bf16":

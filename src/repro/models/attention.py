@@ -111,8 +111,9 @@ def _plain_attention(q, k, v, mask, softcap, ibert_mode=False):
                                         axis=-1)
     else:
         probs = _softmax(scores, softcap)
-    out = jnp.einsum("bksgt,btkd->bskgd", probs.astype(v.dtype), v)
-    return out
+    out = jnp.einsum("bksgt,btkd->bskgd", probs.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return out.astype(v.dtype)
 
 
 def _chunked_attention(q, k, v, q_offset, softcap):

@@ -1117,21 +1117,9 @@ class ContinuousBatchingScheduler:
                 self._decode_spec(step, out, was_active)
                 events, self._events = self._events, []
                 return TickResult(events, out, dispatches + 1, True)
-            step_args = (self.params, self.states, self._cur_tok,
-                         self._cache_index, self._keys, self._active,
-                         self._temp, self._eos, self._gen, self._max_toks)
-            if self.paged:
-                # the jitted step masks the table against `active` itself
-                # (_mask_block_table), so non-decoding rows' writes land
-                # in the trash block no matter what the host passes here;
-                # shared_cols additionally trash-routes writes into
-                # prefix-cache-shared columns (all zeros when prefix
-                # caching is off — same compiled shape either way)
-                step_args += (jnp.asarray(self._block_table),
-                              jnp.asarray(self._shared_cols))
             with self.engine.mesh_ctx():
                 (self.states, tok, cache_index, keys, active, gen,
-                 done) = self._step(*step_args)
+                 done) = self._step(*self._step_args())
             # writable host copies (np.asarray of a jax array is read-only)
             tok = np.array(tok)
             self._cur_tok = tok[:, None].astype(np.int32)
@@ -1162,6 +1150,41 @@ class ContinuousBatchingScheduler:
             dispatches += 1
         events, self._events = self._events, []
         return TickResult(events, out, dispatches, decoded)
+
+    def _step_args(self) -> tuple:
+        """The decode step's arguments for the current slot lanes."""
+        args = (self.params, self.states, self._cur_tok, self._cache_index,
+                self._keys, self._active, self._temp, self._eos, self._gen,
+                self._max_toks)
+        if self.paged:
+            # the jitted step masks the table against `active` itself
+            # (_mask_block_table), so non-decoding rows' writes land in
+            # the trash block no matter what the host passes here;
+            # shared_cols additionally trash-routes writes into
+            # prefix-cache-shared columns (all zeros when prefix caching
+            # is off — same compiled shape either way)
+            args += (jnp.asarray(self._block_table),
+                     jnp.asarray(self._shared_cols))
+        return args
+
+    def precompile(self) -> dict[str, jax.stages.Compiled]:
+        """Compile the single-token decode step — and, with chunked
+        prefill, the full-block chunk-prefill step — for the live slot
+        pool before the first request arrives.  The serve loop's calls
+        reuse these executables; ragged prompt tails still compile on
+        first use.  Returns them by name (``"decode"``,
+        ``"chunk_prefill"``) for inspection (``as_text()``,
+        ``memory_analysis()``)."""
+        with self.engine.mesh_ctx():
+            out = {"decode": self._step.lower(*self._step_args()).compile()}
+            if self.chunked_prefill:
+                out["chunk_prefill"] = self._chunk_prefill.lower(
+                    self.params, self.states,
+                    jnp.zeros((1, self.block_size), jnp.int32),
+                    jnp.int32(0),
+                    jnp.zeros((1, self.table_width), jnp.int32),
+                    jnp.int32(0), jnp.zeros((1,), jnp.int32)).compile()
+        return out
 
     def _slot_of(self, rid: int) -> int | None:
         for slot, req in enumerate(self._slot_req):
@@ -1339,6 +1362,7 @@ class ContinuousBatchingScheduler:
 
 def synthetic_workload(n_requests: int, vocab_size: int, *,
                        max_prompt: int = 8, max_new: int = 16,
+                       min_prompt: int = 1, min_new: int = 1,
                        mean_interarrival: float = 0.0,
                        temperature_choices: Sequence[float] = (0.0, 0.7),
                        eos_rate: float = 0.25, seed: int = 0,
@@ -1362,7 +1386,9 @@ def synthetic_workload(n_requests: int, vocab_size: int, *,
         ``arrival`` its integer-step shadow so the same trace still
         runs through ``run``.
 
-    ``eos_rate`` is the fraction of requests given a random EOS id
+    Prompt lengths are uniform in ``[min_prompt, max_prompt]`` and
+    token budgets in ``[min_new, max_new]``.  ``eos_rate`` is the
+    fraction of requests given a random EOS id
     (which may or may not ever be sampled — both paths are exercised);
     ``priority_choices``/``deadline_ms`` stamp the front-end metadata
     fields uniformly at random / uniformly on all requests.
@@ -1384,7 +1410,7 @@ def synthetic_workload(n_requests: int, vocab_size: int, *,
             t += rng.exponential(1.0 / poisson_rate)
         elif mean_interarrival > 0:
             t += rng.exponential(mean_interarrival)
-        plen = int(rng.integers(1, max_prompt + 1))
+        plen = int(rng.integers(min_prompt, max_prompt + 1))
         eos = int(rng.integers(0, vocab_size)) \
             if rng.random() < eos_rate else -1
         if shared_prefix_len > 0:
@@ -1396,7 +1422,7 @@ def synthetic_workload(n_requests: int, vocab_size: int, *,
             prompt = rng.integers(0, vocab_size, size=plen).tolist()
         reqs.append(Request(
             prompt=prompt,
-            max_tokens=int(rng.integers(1, max_new + 1)),
+            max_tokens=int(rng.integers(min_new, max_new + 1)),
             temperature=float(rng.choice(list(temperature_choices))),
             eos_id=eos, seed=int(rng.integers(0, 2**31 - 1)),
             arrival=int(t), rid=i,

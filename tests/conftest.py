@@ -1,16 +1,24 @@
 """Suite-wide setup.
 
-If the real ``hypothesis`` package is unavailable (this container cannot
-pip-install), register the deterministic shim from ``_hypothesis_shim``
-under that name *before* test modules import it.  When the real package
-is installed it wins, untouched.
+Property tests draw their examples deterministically (``derandomize``)
+and keep no example database, so every run sees the same examples and a
+test run writes nothing into the checkout.
 """
 import importlib.util
-import os
-import sys
+import pathlib
 
-sys.path.insert(0, os.path.dirname(__file__))
+import pytest
+from hypothesis import settings
 
-if importlib.util.find_spec("hypothesis") is None:
-    import _hypothesis_shim
-    sys.modules["hypothesis"] = _hypothesis_shim
+settings.register_profile("repro", derandomize=True, database=None)
+settings.load_profile("repro")
+
+
+@pytest.fixture(scope="session")
+def chip_smoke():
+    """The repository-root ``chip_smoke.py`` as a module."""
+    path = pathlib.Path(__file__).resolve().parents[1] / "chip_smoke.py"
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod

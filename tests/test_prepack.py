@@ -251,6 +251,37 @@ def test_prepacked_model_forward_matches_raw():
                                   np.asarray(logits_packed))
 
 
+@pytest.mark.parametrize("mode", ["int8", "pum"])
+def test_init_served_params_matches_init_then_prepack(mode):
+    """The one-program load path packs what the two-step path (init in
+    f32, then prepack) does.  Fusing init with packing may round the
+    random draws' scaling differently by an ulp, so scales and float
+    leaves agree to f32 rounding and quantised values to one step."""
+    from repro.models import lm
+    cfg = small_test_config(pum=PUMConfig(mode=mode))
+    key = jax.random.PRNGKey(3)
+    fused = lm.init_served_params(cfg, key)
+    two_step = lm.prepack_for_serving(lm.init_params(cfg, key), cfg)
+    is_packed = lambda x: isinstance(x, PackedLinear)  # noqa: E731
+    flat_f, tree_f = jax.tree_util.tree_flatten(fused, is_leaf=is_packed)
+    flat_t, tree_t = jax.tree_util.tree_flatten(two_step, is_leaf=is_packed)
+    assert tree_f == tree_t
+    assert sum(map(is_packed, flat_f)) > 0
+    for a, b in zip(flat_f, flat_t):
+        if is_packed(a):
+            np.testing.assert_allclose(np.asarray(a.scale),
+                                       np.asarray(b.scale), rtol=1e-6)
+            for qa, qb in ((a.wq, b.wq), (a.planes, b.planes)):
+                if qa is not None:
+                    assert qa.dtype == qb.dtype == jnp.int8
+                    assert np.abs(np.asarray(qa, np.int32)
+                                  - np.asarray(qb, np.int32)).max() <= 1
+        else:
+            assert a.dtype == b.dtype
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-6, atol=1e-9)
+
+
 def test_encoder_app_prepack_matches_raw():
     from repro.apps import encoder_app
     cfg = PUMConfig(mode="int8")
