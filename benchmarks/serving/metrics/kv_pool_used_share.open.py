@@ -1,0 +1,8 @@
+"""Mean, over the window's ticks, of the KV pool's blocks in use over
+its blocks (``total_blocks - free_blocks``, read before each tick), in
+an open-loop cell: as it nears 1, new arrivals wait for blocks."""
+
+
+def read(w):
+    return sum(t.kv_used for t in w.ticks) / len(w.ticks) \
+        if w.ticks else None
