@@ -397,6 +397,8 @@ class ServeFrontend:
             # victimless decode fault: the dispatch simply didn't
             # happen; next pump retries the identical step
         if res is not None:
+            # tokens are stamped when the tick that made them returns
+            made = self.clock()
             for rid, idx, tok in res.events:
                 h = self._handles.get(rid)
                 if h is None or h.done:
@@ -405,12 +407,12 @@ class ServeFrontend:
                     self._total_tokens += 1
                     self._c["tokens"].inc()
                     if h.first_token_time is None:
-                        h.first_token_time = now
-                        self._s_ttft.observe((now - h.enq_time) * 1e3)
+                        h.first_token_time = made
+                        self._s_ttft.observe((made - h.enq_time) * 1e3)
                     elif h.last_token_time is not None:
                         self._s_itl.observe(
-                            (now - h.last_token_time) * 1e3)
-                    h.last_token_time = now
+                            (made - h.last_token_time) * 1e3)
+                    h.last_token_time = made
             for rid, comp in res.completions.items():
                 h = self._handles.get(rid)
                 if h is not None:

@@ -58,6 +58,16 @@ raised fault always leaves the slot state machine consistent — the
 chaos suite (``tests/test_chaos.py``) proves survivors stay
 bit-identical and no blocks leak under seeded fault storms.
 
+``tick`` records its phases as ``serve.*`` spans
+(:mod:`repro.serve.spans`), visible under ``jax.profiler``: per
+prefilling slot ``chunk.prepare``, ``chunk.dispatch`` (with the prompt
+rows it feeds: ``rid``, ``start``, ``tokens``, ``last``) and, after a
+prompt's last chunk, ``first_token``; then ``decode.prepare``,
+``decode.dispatch``, ``decode.wait``, ``decode.fetch`` and
+``decode.emit``.  ``first_token`` and ``decode.wait`` are the host's
+blocking reads: from the end of either until the next dispatch, nothing
+the scheduler dispatched is pending on the device.
+
 Paged KV cache + chunked prefill
 --------------------------------
 With ``kv_block_size > 0`` the attention KV state is no longer a private
@@ -123,6 +133,7 @@ from repro.serve.engine import (ServeEngine, make_decode_step,
                                 make_verify_step, sample_token)
 from repro.serve.errors import (InvalidRequest, PoolExhausted,
                                 RequestTooLarge, SchedulerStalled)
+from repro.serve.spans import span
 
 
 # ---------------------------------------------------------------------------
@@ -957,35 +968,47 @@ class ContinuousBatchingScheduler:
         dispatches = 0
         for slot in sorted(self._prefills):
             pf = self._prefills[slot]
+            rid = pf.req.rid
             if fault_hook is not None:
-                fault_hook("chunk", pf.req.rid)
-            if pf.cow_col >= 0:
-                # deferred copy-on-write for a fully-cached prompt: copy
-                # the shared last block into the reserved private one,
-                # repoint the table column, and drop the shared
-                # reference.  Runs *after* the fault hook — a raise
-                # leaves the table still pointing at the shared block
-                # (which shared_cols still write-protects) and the
-                # reserved block in _slot_blocks, so cancel cleans up.
-                src = int(self._block_table[slot, pf.cow_col])
-                with self.engine.mesh_ctx():
-                    self.states = self._cow_copy(
-                        self.states, jnp.int32(src),
-                        jnp.int32(pf.cow_dst))
-                self._block_table[slot, pf.cow_col] = pf.cow_dst
-                self._shared_cols[slot] = pf.cow_col
-                self._slot_blocks[slot].remove(src)
-                self._alloc.release([src])
-                pf.cow_col = pf.cow_dst = -1
-                dispatches += 1
-            chunk = self.block_size if self.chunked_prefill \
-                else len(pf.prompt)
-            c = min(chunk, len(pf.prompt) - pf.pos)
-            toks = jnp.asarray(pf.prompt[pf.pos:pf.pos + c],
-                               jnp.int32)[None]
-            table_row = jnp.asarray(self._block_table[slot:slot + 1])
-            shared_row = jnp.asarray(self._shared_cols[slot:slot + 1])
-            with self.engine.mesh_ctx():
+                fault_hook("chunk", rid)
+            with span("chunk.prepare", rid=rid):
+                if pf.cow_col >= 0:
+                    # deferred copy-on-write for a fully-cached prompt:
+                    # copy the shared last block into the reserved
+                    # private one, repoint the table column, and drop
+                    # the shared reference.  Runs *after* the fault hook
+                    # — a raise leaves the table still pointing at the
+                    # shared block (which shared_cols still
+                    # write-protects) and the reserved block in
+                    # _slot_blocks, so cancel cleans up.
+                    src = int(self._block_table[slot, pf.cow_col])
+                    with self.engine.mesh_ctx():
+                        self.states = self._cow_copy(
+                            self.states, jnp.int32(src),
+                            jnp.int32(pf.cow_dst))
+                    self._block_table[slot, pf.cow_col] = pf.cow_dst
+                    self._shared_cols[slot] = pf.cow_col
+                    self._slot_blocks[slot].remove(src)
+                    self._alloc.release([src])
+                    pf.cow_col = pf.cow_dst = -1
+                    dispatches += 1
+                chunk = self.block_size if self.chunked_prefill \
+                    else len(pf.prompt)
+                c = min(chunk, len(pf.prompt) - pf.pos)
+                toks = jnp.asarray(pf.prompt[pf.pos:pf.pos + c],
+                                   jnp.int32)[None]
+                # copies: on the CPU an upload may alias the host array,
+                # and _register_prefix rewrites shared_cols before this
+                # chunk has run
+                table_row = jnp.asarray(
+                    self._block_table[slot:slot + 1].copy())
+                shared_row = jnp.asarray(
+                    self._shared_cols[slot:slot + 1].copy())
+            # the prompt rows this dispatch feeds: `tokens` prompt
+            # positions from `start`; `last` completes the prompt
+            with span("chunk.dispatch", rid=rid, start=pf.pos, tokens=c,
+                      last=pf.pos + c == len(pf.prompt)), \
+                    self.engine.mesh_ctx():
                 self.states, logits = self._chunk_prefill(
                     self.params, self.states, toks, jnp.int32(pf.pos),
                     table_row, jnp.int32(slot), shared_row)
@@ -1009,8 +1032,9 @@ class ContinuousBatchingScheduler:
             del self._prefills[slot]
             req = pf.req
             self._register_prefix(slot, pf)
-            key = jax.random.PRNGKey(req.seed)
-            tok0 = int(sample_token(logits, key, req.temperature)[0, 0])
+            with span("first_token", rid=rid):   # waits for the chunk
+                key = jax.random.PRNGKey(req.seed)
+                tok0 = int(sample_token(logits, key, req.temperature)[0, 0])
             if tok0 == req.eos_id or req.max_tokens == 1:
                 reason = "eos" if tok0 == req.eos_id else "length"
                 out[req.rid] = Completion(
@@ -1042,57 +1066,64 @@ class ContinuousBatchingScheduler:
         normal streaming event, bit-identical to the single-token path.
         """
         k = self.speculate_k
-        contexts: list[list[int] | None] = [None] * self.num_slots
-        for slot in np.nonzero(was_active)[0]:
-            req = self._slot_req[slot]
-            contexts[slot] = (list(int(t) for t in req.prompt)
-                              + self._slot_toks[slot])
-        drafts = spec_mod.build_drafts(self._drafter, contexts, k,
-                                       self.cfg.vocab_size)
-        with self.engine.mesh_ctx():
+        with span("decode.prepare"):
+            contexts: list[list[int] | None] = [None] * self.num_slots
+            for slot in np.nonzero(was_active)[0]:
+                req = self._slot_req[slot]
+                contexts[slot] = (list(int(t) for t in req.prompt)
+                                  + self._slot_toks[slot])
+            drafts = jnp.asarray(spec_mod.build_drafts(
+                self._drafter, contexts, k, self.cfg.vocab_size))
+            table = jnp.asarray(self._block_table)
+            shared = jnp.asarray(self._shared_cols)
+        with span("decode.dispatch", rows=int(was_active.sum())), \
+                self.engine.mesh_ctx():
             (self.states, emitted, adv, cache_index, keys, active, gen,
              done) = self._spec_step(
-                self.params, self.states, self._cur_tok,
-                jnp.asarray(drafts), self._cache_index, self._keys,
-                self._active, self._temp, self._eos, self._gen,
-                self._max_toks, jnp.asarray(self._block_table),
-                jnp.asarray(self._shared_cols))
-        emitted = np.array(emitted)
-        adv = np.array(adv)
-        self._cache_index = np.array(cache_index)
-        self._keys = np.array(keys)
-        self._active = np.array(active)
-        self._gen = np.array(gen)
-        done = np.asarray(done)
+                self.params, self.states, self._cur_tok, drafts,
+                self._cache_index, self._keys, self._active, self._temp,
+                self._eos, self._gen, self._max_toks, table, shared)
+        with span("decode.wait"):
+            jax.block_until_ready((emitted, adv, cache_index, keys, active,
+                                   gen, done))
+        with span("decode.fetch"):
+            emitted = np.array(emitted)
+            adv = np.array(adv)
+            self._cache_index = np.array(cache_index)
+            self._keys = np.array(keys)
+            self._active = np.array(active)
+            self._gen = np.array(gen)
+            done = np.asarray(done)
 
-        n_rows = int(was_active.sum())
-        self._spec_steps += 1
-        self._spec_rows += n_rows
-        self._spec_proposed += k * n_rows
-        for slot in np.nonzero(was_active)[0]:
-            req = self._slot_req[slot]
-            m = int(adv[slot])
-            self._spec_accepted += m - 1
-            self._spec_emitted += m
-            for j in range(m):
-                tok = int(emitted[slot, j])
-                self._slot_toks[slot].append(tok)
-                self._events.append(
-                    (req.rid, len(self._slot_toks[slot]) - 1, tok))
-            self._cur_tok[slot, 0] = int(emitted[slot, m - 1])
-            if done[slot]:
-                # the advance cap makes the last emitted token the
-                # decider: EOS-capped rows end exactly on their EOS
-                reason = ("eos"
-                          if int(emitted[slot, m - 1]) == req.eos_id
-                          else "length")
-                out[req.rid] = Completion(
-                    req.rid, list(int(t) for t in req.prompt),
-                    self._slot_toks[slot], reason,
-                    int(self._slot_admitted[slot]), step)
-                self._slot_req[slot] = None
-                self._slot_toks[slot] = []
-                self._retire_paged_slot(slot)
+        with span("decode.emit"):
+            n_rows = int(was_active.sum())
+            self._spec_steps += 1
+            self._spec_rows += n_rows
+            self._spec_proposed += k * n_rows
+            for slot in np.nonzero(was_active)[0]:
+                req = self._slot_req[slot]
+                m = int(adv[slot])
+                self._spec_accepted += m - 1
+                self._spec_emitted += m
+                for j in range(m):
+                    tok = int(emitted[slot, j])
+                    self._slot_toks[slot].append(tok)
+                    self._events.append(
+                        (req.rid, len(self._slot_toks[slot]) - 1, tok))
+                self._cur_tok[slot, 0] = int(emitted[slot, m - 1])
+                if done[slot]:
+                    # the advance cap makes the last emitted token the
+                    # decider: EOS-capped rows end exactly on their EOS
+                    reason = ("eos"
+                              if int(emitted[slot, m - 1]) == req.eos_id
+                              else "length")
+                    out[req.rid] = Completion(
+                        req.rid, list(int(t) for t in req.prompt),
+                        self._slot_toks[slot], reason,
+                        int(self._slot_admitted[slot]), step)
+                    self._slot_req[slot] = None
+                    self._slot_toks[slot] = []
+                    self._retire_paged_slot(slot)
 
     def tick(self, step: int = 0,
              fault_hook: Callable[[str, int | None], None] | None = None,
@@ -1106,21 +1137,40 @@ class ContinuousBatchingScheduler:
         that dispatch yet, so the state machine stays consistent and the
         driver can cancel/retry the victim and simply tick again.
         """
-        out: dict[int, Completion] = {}
-        dispatches = self._feed_prefills(step, out, fault_hook)
-        decoded = False
-        if self._active.any():
-            if fault_hook is not None:
-                fault_hook("decode", None)
-            was_active = self._active.copy()
-            if self.speculate_k > 0:
-                self._decode_spec(step, out, was_active)
-                events, self._events = self._events, []
-                return TickResult(events, out, dispatches + 1, True)
-            with self.engine.mesh_ctx():
-                (self.states, tok, cache_index, keys, active, gen,
-                 done) = self._step(*self._step_args())
-            # writable host copies (np.asarray of a jax array is read-only)
+        with span("tick", step=step):
+            out: dict[int, Completion] = {}
+            dispatches = self._feed_prefills(step, out, fault_hook)
+            decoded = False
+            if self._active.any():
+                if fault_hook is not None:
+                    fault_hook("decode", None)
+                was_active = self._active.copy()
+                if self.speculate_k > 0:
+                    self._decode_spec(step, out, was_active)
+                    events, self._events = self._events, []
+                    return TickResult(events, out, dispatches + 1, True)
+                self._decode(step, out, was_active)
+                decoded = True
+                dispatches += 1
+            events, self._events = self._events, []
+            return TickResult(events, out, dispatches, decoded)
+
+    def _decode(self, step: int, out: dict[int, Completion],
+                was_active: np.ndarray) -> None:
+        """One slot-wise decode dispatch: every live slot advances one
+        token; finished rows retire into ``out``."""
+        with span("decode.prepare"):
+            args = self._step_args()
+        with span("decode.dispatch", rows=int(was_active.sum())), \
+                self.engine.mesh_ctx():
+            (self.states, tok, cache_index, keys, active, gen,
+             done) = self._step(*args)
+        with span("decode.wait"):
+            jax.block_until_ready((tok, cache_index, keys, active, gen,
+                                   done))
+        with span("decode.fetch"):
+            # writable host copies (np.asarray of a jax array is
+            # read-only)
             tok = np.array(tok)
             self._cur_tok = tok[:, None].astype(np.int32)
             self._cache_index = np.array(cache_index)
@@ -1129,6 +1179,7 @@ class ContinuousBatchingScheduler:
             self._gen = np.array(gen)
             done = np.asarray(done)
 
+        with span("decode.emit"):
             for slot in np.nonzero(was_active)[0]:
                 req = self._slot_req[slot]
                 self._slot_toks[slot].append(int(tok[slot]))
@@ -1146,10 +1197,6 @@ class ContinuousBatchingScheduler:
                     self._slot_toks[slot] = []
                     if self.paged:
                         self._retire_paged_slot(slot)
-            decoded = True
-            dispatches += 1
-        events, self._events = self._events, []
-        return TickResult(events, out, dispatches, decoded)
 
     def _step_args(self) -> tuple:
         """The decode step's arguments for the current slot lanes."""

@@ -327,6 +327,33 @@ def test_fault_retry_expiring_in_backoff_surfaces_as_expired():
 # Cancellation / drain / close / preemption
 # ---------------------------------------------------------------------------
 
+def test_tokens_are_stamped_after_the_tick_that_made_them(monkeypatch):
+    """A clock that moves while ``tick`` runs: TTFT and the gaps between
+    tokens include the tick that produced each token."""
+    sched = _sched()
+    fe = _fe(sched)
+    tick = sched.tick
+
+    def slow_tick(*args, **kw):
+        fe.clock.advance(0.5)
+        return tick(*args, **kw)
+
+    monkeypatch.setattr(sched, "tick", slow_tick)
+    # 5 prompt tokens in blocks of 4: two chunk ticks, the second also
+    # decodes, so its tick emits tokens 0 and 1 and each later tick one
+    h = fe.submit(Request([1, 2, 3, 4, 5], max_tokens=3, seed=5, rid=0))
+    while not h.done:
+        fe._pump()
+    assert h.result_nowait().ok
+    assert h.first_token_time == pytest.approx(1.0)
+    assert h.last_token_time == pytest.approx(1.5)
+    snap = fe.metrics.snapshot()
+    assert snap["serve.ttft_ms_p50"] == pytest.approx(1000.0)
+    assert snap["serve.itl_ms_p50"] == pytest.approx(0.0)
+    assert snap["serve.itl_ms_p99"] == pytest.approx(500.0)
+    _assert_clean(sched)
+
+
 def test_handle_cancel_mid_decode():
     sched = _sched()
     fe = _fe(sched)

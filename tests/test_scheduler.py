@@ -9,6 +9,7 @@ across state families (dense KV, xlstm) and execution modes
 (bf16 / int8 / pum).
 """
 import jax
+import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
@@ -492,6 +493,51 @@ def test_prefix_cache_cancellation_mid_decode_leaks_nothing():
     _assert_prefix_clean(sched)
     sched.flush_prefix_cache()
     assert sched._alloc.live_blocks == 0
+
+
+def _aligned(a):
+    """A copy of ``a`` at a 64-byte-aligned address."""
+    buf = np.zeros(a.nbytes + 64, np.uint8)
+    off = -buf.ctypes.data % 64
+    out = buf[off:off + a.nbytes].view(a.dtype).reshape(a.shape)
+    out[...] = a
+    return out
+
+
+def test_chunk_inputs_are_snapshots_of_the_host_tables(monkeypatch):
+    """A chunk dispatch uploads copies of its slot's table rows: the host
+    rewrites them before the chunk has run (prefix registration widens
+    ``shared_cols`` right after a prompt's last chunk is dispatched),
+    and on the CPU an upload of the host array itself aliases it."""
+    sched = _sched(num_slots=1, kv_block_size=4, chunked_prefill=True,
+                   prefix_cache=True)
+    uploads = []
+    chunk = sched._chunk_prefill
+
+    def spy(params, states, tokens, start, table_row, slot, shared):
+        uploads.append((table_row, shared))
+        return chunk(params, states, tokens, start, table_row, slot, shared)
+
+    monkeypatch.setattr(sched, "_chunk_prefill", spy)
+    assert sched.start_request(Request(list(range(1, 9)), max_tokens=3,
+                                       seed=3, rid=0), 0) is None
+    # 64-byte-aligned host tables, which the CPU backend uploads without
+    # a copy
+    sched._block_table = _aligned(sched._block_table)
+    sched._shared_cols = _aligned(sched._shared_cols)
+    sched.tick(0)
+    table, shared = (np.array(x) for x in uploads[0])
+    saved = sched._block_table.copy(), sched._shared_cols.copy()
+    sched._block_table += 1
+    sched._shared_cols += 1
+    try:
+        assert (np.array(uploads[0][0]) == table).all()
+        assert (np.array(uploads[0][1]) == shared).all()
+    finally:
+        sched._block_table[:] = saved[0]
+        sched._shared_cols[:] = saved[1]
+    sched.drain(1)
+    _assert_prefix_clean(sched)
 
 
 def test_prefix_cache_requires_paged_pool():
