@@ -602,11 +602,11 @@ def bench_serve_kernel(small: bool = False) -> list[Row]:
     q = jnp.asarray(rng.normal(size=(b, s, kvh, g, hd)), jnp.float32)
     kn = jnp.asarray(rng.normal(size=(b, s, kvh, hd)), jnp.float32)
     vn = jnp.asarray(rng.normal(size=(b, s, kvh, hd)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(nb, bs, kvh, hd)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(nb, bs, kvh, hd)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(1, nb, bs, kvh * hd)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(1, nb, bs, kvh * hd)), jnp.float32)
     table = jnp.asarray(np.arange(1, 1 + b * w).reshape(b, w), jnp.int32)
     ci = jnp.asarray(rng.integers(0, w * bs - s + 1, size=(b,)), jnp.int32)
-    args = (q, kn, vn, kp, vp, table, table, ci)
+    args = (q, kn, vn, kp, vp, table, table, ci, jnp.int32(0))
 
     def attn(backend):
         return jax.jit(lambda *a: paged_attention(*a, softcap=0.0,

@@ -395,26 +395,31 @@ def serve_param_specs(params: Any) -> Any:
         is_leaf=lambda v: isinstance(v, PackedLinear))
 
 
-def serve_state_specs(states: Any, mesh: Mesh | None = None) -> Any:
+def serve_state_specs(states: Any, mesh: Mesh | None = None, *,
+                      kv_heads: int) -> Any:
     """Specs for a serving decode-state tree (contiguous or paged KV).
 
-    KV storage shards the KV-head axis over ``model`` (head-divisibility
+    KV storage shards the KV heads over ``model`` (head-divisibility
     guarded): contiguous caches ``[G, B, T, KV, hd]`` on axis 3, paged
-    pools ``[G, NB, bs, KV, hd]`` on axis 3 as well — every device owns
-    the full block pool for its heads, so the per-row block-table
-    scatter/gather stays device-local.  Recurrent rows (xlstm / ssm)
-    and the tiny per-slot lanes stay replicated; batch shards over
-    ``data`` when that axis exists (it does not on the 1-D serving
-    mesh).
+    pools ``[G, NB, bs, KV * hd]`` on their folded lane axis — a
+    contiguous run of whole heads per shard, only when ``kv_heads``
+    divides the axis (``validate_tp`` requires it of a served model) —
+    so every device owns the full block pool for its heads and the
+    per-row block-table scatter/gather stays device-local.  Recurrent
+    rows (xlstm / ssm) and the tiny per-slot lanes stay replicated;
+    batch shards over ``data`` when that axis exists (it does not on the
+    1-D serving mesh).
     """
     mesh = mesh or current_mesh()
     assert mesh is not None, "serve_state_specs needs a mesh"
+    whole_heads = kv_heads % _axis_sizes(mesh).get("model", 1) == 0
 
     def leaf_spec(path, leaf):
         names = tuple(_key_str(k) for k in path)
         shape = leaf.shape
         if names and names[-1] in ("k_pool", "v_pool"):
-            return _guard((None, None, None, "model", None), shape, mesh)
+            lanes = "model" if whole_heads else None
+            return _guard((None, None, None, lanes), shape, mesh)
         if names and names[-1] in ("k", "v") and len(shape) == 5:
             return _guard((None, "data", None, "model", None), shape, mesh)
         spec = ((None, "data") + (None,) * (len(shape) - 2)) \

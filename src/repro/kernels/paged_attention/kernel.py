@@ -25,9 +25,12 @@ The pools stay in HBM (``memory_space=ANY``) and enter as
 in place (the gather DMAs start after the row's own write DMAs have
 landed — the decode token attends itself).  Only one row's ``T``
 positions are ever resident in VMEM, so the pool can be as large as HBM
-allows.  The kernel sees each pool as ``[NB, bs, KV * hd]`` — the same
-bytes with the head axis folded into the lanes — so every DMA moves
-lane-dense rows.  The grid axis is ``arbitrary`` (sequential): rows'
+allows.  Each pool is the whole layer stack, ``[L, NB, bs, KV * hd]``
+(the head axis folded into the lanes, the layout the pool is stored
+in), and the kernel addresses layer ``layer`` (an SMEM scalar) inside
+it: every DMA moves lane-dense rows of that layer, and no other layer's
+bytes are read or written — the layer scan hands the stacked pool
+through untouched.  The grid axis is ``arbitrary`` (sequential): rows'
 stores target disjoint physical blocks except the trash block, whose
 content is never attended.
 
@@ -55,17 +58,18 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _paged_attention_kernel(idx_ref, table_ref, wtable_ref, q_ref, kn_ref,
-                            vn_ref, kp_in_ref, vp_in_ref, kp_ref, vp_ref,
-                            o_ref, k_scr, v_scr, sem, *, s_len: int,
-                            bs: int, w: int, t: int, kvh: int, hd: int,
-                            softcap: float):
+def _paged_attention_kernel(idx_ref, table_ref, wtable_ref, layer_ref,
+                            q_ref, kn_ref, vn_ref, kp_in_ref, vp_in_ref,
+                            kp_ref, vp_ref, o_ref, k_scr, v_scr, sem, *,
+                            s_len: int, bs: int, w: int, t: int, kvh: int,
+                            hd: int, softcap: float):
     """One batch row.  kp_ref/vp_ref alias the input pools (kp_in_ref /
     vp_in_ref are the pre-aliasing handles, unused: all DMAs go through
     the aliased refs so a row's gather sees its own stores)."""
     del kp_in_ref, vp_in_ref
     b = pl.program_id(0)
     base = idx_ref[b]
+    layer = layer_ref[0]
 
     def block_copy(col, to_pool, wait):
         """Start (or wait for) the K and V DMAs of one table column:
@@ -75,7 +79,7 @@ def _paged_attention_kernel(idx_ref, table_ref, wtable_ref, q_ref, kn_ref,
         phys = (wtable_ref if to_pool else table_ref)[b, col]
         rows = pl.ds(pl.multiple_of(col * bs, bs), bs)
         for pool_ref, scr in ((kp_ref, k_scr), (vp_ref, v_scr)):
-            src, dst = pool_ref.at[phys], scr.at[rows]
+            src, dst = pool_ref.at[layer, phys], scr.at[rows]
             if to_pool:
                 src, dst = dst, src
             cp = pltpu.make_async_copy(src, dst, sem)
@@ -140,21 +144,22 @@ def _paged_attention_kernel(idx_ref, table_ref, wtable_ref, q_ref, kn_ref,
 def paged_attention_pallas(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
                            k_pool: jax.Array, v_pool: jax.Array,
                            block_table: jax.Array, write_table: jax.Array,
-                           cache_index: jax.Array, *,
+                           cache_index: jax.Array, layer: jax.Array, *,
                            kv_len: int | None = None, softcap: float = 0.0,
                            interpret: bool,
                            ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """q: [B,S,KV,G,hd]; k_new/v_new: [B,S,KV,hd]; pools: [NB,bs,KV,hd];
-    tables: [B,W] int32; cache_index: [B] int32.  Returns (k_pool,
-    v_pool, out[B,S,KV,G,hd]) with the pools updated in place (aliased).
+    """q: [B,S,KV,G,hd]; k_new/v_new: [B,S,KV,hd]; pools: [L,NB,bs,KV*hd];
+    tables: [B,W] int32; cache_index: [B] int32; layer: int32 scalar,
+    the pool layer this call reads and writes.  Returns (k_pool, v_pool,
+    out[B,S,KV,G,hd]) with the pools updated in place (aliased).
     ``interpret`` runs the body through the Pallas interpreter (any
     backend) instead of compiling it for the TPU.
     """
     b, s_len, kvh, g, hd = q.shape
-    nb, bs = k_pool.shape[:2]
+    bs, lanes = k_pool.shape[2:]
+    assert lanes == kvh * hd, (k_pool.shape, q.shape)
     w = block_table.shape[1]
     t = w * bs if kv_len is None else min(kv_len, w * bs)
-    lanes = kvh * hd
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     row = pl.BlockSpec((1, s_len, lanes), lambda i: (i, 0, 0))
@@ -165,25 +170,26 @@ def paged_attention_pallas(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
     kp, vp, out = pl.pallas_call(
         kernel,
         grid=(b,),
-        in_specs=[smem, smem, smem,          # cache_index, tables
+        in_specs=[smem, smem, smem, smem,    # cache_index, tables, layer
                   qspec, row, row,           # q, k_new, v_new
                   hbm, hbm],                 # k_pool, v_pool
         out_specs=(hbm, hbm, qspec),
         out_shape=(
-            jax.ShapeDtypeStruct((nb, bs, lanes), k_pool.dtype),
-            jax.ShapeDtypeStruct((nb, bs, lanes), v_pool.dtype),
+            jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
+            jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype),
             jax.ShapeDtypeStruct((b, s_len, kvh, g, hd), v_pool.dtype),
         ),
         scratch_shapes=[pltpu.VMEM((w * bs, lanes), k_pool.dtype),
                         pltpu.VMEM((w * bs, lanes), v_pool.dtype),
                         pltpu.SemaphoreType.DMA],
-        input_output_aliases={6: 0, 7: 1},
+        input_output_aliases={7: 0, 8: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_attention",
-    )(cache_index, block_table, write_table, q,
+    )(cache_index, block_table, write_table,
+      jnp.reshape(layer, (1,)).astype(jnp.int32), q,
       k_new.reshape(b, s_len, lanes).astype(k_pool.dtype),
       v_new.reshape(b, s_len, lanes).astype(v_pool.dtype),
-      k_pool.reshape(nb, bs, lanes), v_pool.reshape(nb, bs, lanes))
-    return kp.reshape(k_pool.shape), vp.reshape(v_pool.shape), out
+      k_pool, v_pool)
+    return kp, vp, out

@@ -18,28 +18,31 @@ NEG_INF = -1e30
 def paged_attention_ref(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
                         k_pool: jax.Array, v_pool: jax.Array,
                         block_table: jax.Array, write_table: jax.Array,
-                        cache_index: jax.Array, *,
+                        cache_index: jax.Array, layer: jax.Array, *,
                         kv_len: int | None = None, softcap: float = 0.0,
                         ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Scatter + gather + plain-softmax attention over the block pool.
+    """Scatter + gather + plain-softmax attention over one layer of the
+    stacked block pool.
 
     q: [B, S, KV, G, hd]; k_new/v_new: [B, S, KV, hd];
-    k_pool/v_pool: [NB, bs, KV, hd]; block_table/write_table: [B, W]
-    int32 (0 = trash block); cache_index: [B] int32.  Returns the
-    updated pools and the [B, S, KV, G, hd] attention output (v dtype).
+    k_pool/v_pool: [L, NB, bs, KV * hd]; block_table/write_table:
+    [B, W] int32 (0 = trash block); cache_index: [B] int32; layer: the
+    int32 pool layer read and written.  Returns the updated pools and
+    the [B, S, KV, G, hd] attention output (v dtype).
     """
-    b, s = k_new.shape[:2]
-    bs = k_pool.shape[1]
+    b, s, kvh, hd = k_new.shape
+    bs = k_pool.shape[2]
     w = block_table.shape[1]
     pos = cache_index[:, None] + jnp.arange(s)[None, :]            # [B, S]
     slot_col = jnp.clip(pos // bs, 0, w - 1)
     phys = jnp.take_along_axis(write_table, slot_col, axis=1)      # [B, S]
     off = pos % bs
-    k_pool = k_pool.at[phys, off].set(k_new.astype(k_pool.dtype))
-    v_pool = v_pool.at[phys, off].set(v_new.astype(v_pool.dtype))
-    kvh, hd = k_pool.shape[2:]
-    k_all = k_pool[block_table].reshape(b, w * bs, kvh, hd)
-    v_all = v_pool[block_table].reshape(b, w * bs, kvh, hd)
+    k_pool = k_pool.at[layer, phys, off].set(
+        k_new.reshape(b, s, kvh * hd).astype(k_pool.dtype))
+    v_pool = v_pool.at[layer, phys, off].set(
+        v_new.reshape(b, s, kvh * hd).astype(v_pool.dtype))
+    k_all = k_pool[layer, block_table].reshape(b, w * bs, kvh, hd)
+    v_all = v_pool[layer, block_table].reshape(b, w * bs, kvh, hd)
     if kv_len is not None and kv_len < w * bs:
         k_all = k_all[:, :kv_len]
         v_all = v_all[:, :kv_len]

@@ -49,6 +49,12 @@ def init_attention(key, cfg: ModelConfig, cross: bool = False) -> Params:
     }
 
 
+def is_paged_cache(state: Any) -> bool:
+    """A paged KV pool (``make_paged_cache``), as opposed to a
+    contiguous cache or a recurrent state."""
+    return isinstance(state, dict) and "k_pool" in state
+
+
 def make_cache(cfg: ModelConfig, batch: int, max_len: int,
                dtype=jnp.bfloat16) -> Params:
     hd = cfg.resolved_head_dim
@@ -58,27 +64,21 @@ def make_cache(cfg: ModelConfig, batch: int, max_len: int,
     }
 
 
-def make_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
-                     dtype=jnp.bfloat16) -> Params:
+def make_paged_cache(cfg: ModelConfig, num_groups: int, num_blocks: int,
+                     block_size: int, dtype=jnp.bfloat16) -> Params:
     """One shared pool of KV blocks instead of per-slot windows.
 
-    ``num_blocks`` counts *physical* blocks, including the reserved
-    trash block at id 0 (``serve.kv_pool`` allocates usable ids from 1).
-    Slots address it through a per-slot block table; there is no batch
-    axis — that's the whole point.
+    ``[num_groups, num_blocks, block_size, kv_heads * head_dim]``: the
+    layer-group stack, then *physical* blocks (the reserved trash block
+    at id 0 included — ``serve.kv_pool`` allocates usable ids from 1),
+    with the KV-head axis folded into the lanes, the layout the
+    paged-attention kernel DMAs.  Slots address it through a per-slot
+    block table; there is no batch axis — that's the whole point.
     """
-    hd = cfg.resolved_head_dim
-    shape = (num_blocks, block_size, cfg.num_kv_heads, hd)
+    shape = (num_groups, num_blocks, block_size,
+             cfg.num_kv_heads * cfg.resolved_head_dim)
     return {"k_pool": jnp.zeros(shape, dtype),
             "v_pool": jnp.zeros(shape, dtype)}
-
-
-def paged_cache_shape(cfg: ModelConfig, num_blocks: int, block_size: int,
-                      dtype=jnp.bfloat16) -> Params:
-    hd = cfg.resolved_head_dim
-    sds = jax.ShapeDtypeStruct
-    shape = (num_blocks, block_size, cfg.num_kv_heads, hd)
-    return {"k_pool": sds(shape, dtype), "v_pool": sds(shape, dtype)}
 
 
 def cache_shape(cfg: ModelConfig, batch: int, max_len: int,
@@ -202,18 +202,20 @@ def paged_write_cells(write_table: jax.Array, cache_index: jax.Array,
     return phys, pos % block_size
 
 
-def _paged_update_and_gather(cache: Params, k: jax.Array, v: jax.Array,
-                             block_table: jax.Array, cache_index: jax.Array,
-                             kv_len: int | None,
+def _paged_update_and_gather(cache: Params, layer: jax.Array, k: jax.Array,
+                             v: jax.Array, block_table: jax.Array,
+                             cache_index: jax.Array, kv_len: int | None,
                              write_table: jax.Array | None = None,
                              ) -> tuple[Params, jax.Array, jax.Array,
                                         jax.Array]:
-    """Scatter this step's K/V through the block table into the shared
-    pool, then gather each row's logical cache view back out.
+    """Scatter this step's K/V through the block table into layer
+    ``layer`` of the shared stacked pool, then gather each row's logical
+    cache view back out of that layer.
 
     k/v: [B, S, KV, hd] new entries for rows starting at positions
-    ``cache_index`` ([B] int32).  ``block_table``: [B, W] physical block
-    ids (0 = the trash block: empty/retired rows write there and their
+    ``cache_index`` ([B] int32).  The pools are [L, NB, bs, KV * hd]
+    (``make_paged_cache``).  ``block_table``: [B, W] physical block ids
+    (0 = the trash block: empty/retired rows write there and their
     garbage is never attended).  Returns the updated cache, the gathered
     [B, T, KV, hd] views, and the [B, S] absolute query positions.
 
@@ -229,27 +231,27 @@ def _paged_update_and_gather(cache: Params, k: jax.Array, v: jax.Array,
     compiled reduction order, hence bitwise numerics — match the
     contiguous cache exactly.
     """
-    b, s = k.shape[:2]
-    bs = cache["k_pool"].shape[1]
+    b, s, kvh, hd = k.shape
+    bs = cache["k_pool"].shape[2]
     w = block_table.shape[1]
     if write_table is None:
         write_table = block_table
     pos = cache_index[:, None] + jnp.arange(s)[None, :]            # [B, S]
     phys, off = paged_write_cells(write_table, cache_index, s, bs)
     with jax.named_scope("kv_pool_write"):
-        k_pool = cache["k_pool"].at[phys, off].set(
-            k.astype(cache["k_pool"].dtype))
-        v_pool = cache["v_pool"].at[phys, off].set(
-            v.astype(cache["v_pool"].dtype))
-    # tensor-parallel serving: the pool and its gathered per-row views
-    # shard the KV-head axis, so both the scatter and the block-table
-    # gather stay device-local (each shard owns the whole pool for its
-    # heads); no-ops without an active mesh
-    k_pool = shard_act(k_pool, None, None, "model", None)
-    v_pool = shard_act(v_pool, None, None, "model", None)
-    kvh, hd = k_pool.shape[2:]
-    k_all = k_pool[block_table].reshape(b, w * bs, kvh, hd)
-    v_all = v_pool[block_table].reshape(b, w * bs, kvh, hd)
+        k_pool = cache["k_pool"].at[layer, phys, off].set(
+            k.reshape(b, s, kvh * hd).astype(cache["k_pool"].dtype))
+        v_pool = cache["v_pool"].at[layer, phys, off].set(
+            v.reshape(b, s, kvh * hd).astype(cache["v_pool"].dtype))
+    # tensor-parallel serving: the pool shards its folded lanes over
+    # ``model`` (whole KV heads per shard: KV % tp == 0) and the
+    # gathered per-row views their KV-head axis, so both the scatter
+    # and the block-table gather stay device-local (each shard owns the
+    # whole pool for its heads); no-ops without an active mesh
+    k_pool = shard_act(k_pool, None, None, None, "model")
+    v_pool = shard_act(v_pool, None, None, None, "model")
+    k_all = k_pool[layer, block_table].reshape(b, w * bs, kvh, hd)
+    v_all = v_pool[layer, block_table].reshape(b, w * bs, kvh, hd)
     if kv_len is not None and kv_len < w * bs:
         k_all = k_all[:, :kv_len]
         v_all = v_all[:, :kv_len]
@@ -267,6 +269,7 @@ def attention(p: Params, x: jax.Array, cfg: ModelConfig, *,
               block_table: jax.Array | None = None,
               kv_len: int | None = None,
               write_table: jax.Array | None = None,
+              pool_layer: jax.Array | int | None = None,
               ) -> tuple[jax.Array, Params | None]:
     """x: [B, S, D].  Modes:
       * train/prefill (cache None, cross_kv None): causal self-attention;
@@ -278,9 +281,11 @@ def attention(p: Params, x: jax.Array, cfg: ModelConfig, *,
       * paged decode (cache holds ``k_pool``/``v_pool`` and
         ``block_table`` is set): same semantics, but rows address one
         shared block pool through their block-table row instead of a
-        private contiguous window.  ``kv_len`` is the engine window the
-        gathered view is cropped to (bit-exactness vs the contiguous
-        cache).
+        private contiguous window.  The pools are the whole layer stack
+        (``make_paged_cache``) and ``pool_layer`` picks this layer's
+        slice, read and written in place.  ``kv_len`` is the engine
+        window the gathered view is cropped to (bit-exactness vs the
+        contiguous cache).
       * cross attention (cross_kv set): encoder-decoder attention.
     """
     b, s, d = x.shape
@@ -306,8 +311,8 @@ def attention(p: Params, x: jax.Array, cfg: ModelConfig, *,
         cache_index = jnp.asarray(cache_index)
         assert cache_index.ndim == 1, \
             "paged attention is slot-wise: cache_index must be [B]"
-        assert block_table is not None, \
-            "paged attention requires a block_table"
+        assert block_table is not None and pool_layer is not None, \
+            "paged attention requires a block_table and a pool_layer"
         # the paged path reduces with plain softmax: beyond this the
         # contiguous oracle switches to online-softmax (_chunked_attention,
         # a different reduction order) and the [B,S,T] score tensor stops
@@ -329,12 +334,12 @@ def attention(p: Params, x: jax.Array, cfg: ModelConfig, *,
                     block_table,
                     write_table if write_table is not None
                     else block_table,
-                    cache_index, kv_len=kv_len,
+                    cache_index, pool_layer, kv_len=kv_len,
                     softcap=cfg.attn_logit_softcap, backend=backend)
             cache = {**cache, "k_pool": kp, "v_pool": vp}
         else:
             cache, k_all, v_all, qpos = _paged_update_and_gather(
-                cache, k, v, block_table, cache_index, kv_len,
+                cache, pool_layer, k, v, block_table, cache_index, kv_len,
                 write_table=write_table)
             kpos = jnp.arange(k_all.shape[1])
             mask = kpos[None, None, :] <= qpos[..., None]          # [B,S,T]

@@ -11,7 +11,8 @@ Covers all ten assigned architectures through ``ModelConfig``:
 Layer stacking scans over repeating *groups* (period = the heterogeneous
 pattern length), so jamba's 32 layers compile as a scan over 4 groups of 8
 distinct blocks, and dense models as a scan over L groups of 1.  Decode
-states ride through the scan as per-group stacked pytrees.
+states ride through the scan as per-group stacked pytrees; paged KV
+pools ride whole in the scan carry, addressed by group index.
 """
 from __future__ import annotations
 
@@ -136,8 +137,9 @@ def init_paged_state(cfg: ModelConfig, batch: int, max_len: int, *,
     """Decode states with attention KV paged into one shared block pool.
 
     Attention period-positions get ``[n_groups, num_blocks + 1,
-    block_size, kv_heads, head_dim]`` pools (physical block 0 is the
-    reserved trash block — ``serve.kv_pool``); recurrent families keep
+    block_size, kv_heads * head_dim]`` pools (physical block 0 is the
+    reserved trash block — ``serve.kv_pool``; the heads folded into the
+    lanes, ``attention.make_paged_cache``); recurrent families keep
     their per-slot ``[n_groups, batch, ...]`` rows.  Total KV storage is
     ``(num_blocks + 1) * block_size`` positions per layer group instead
     of ``batch * max_len``.
@@ -147,14 +149,14 @@ def init_paged_state(cfg: ModelConfig, batch: int, max_len: int, *,
     out = []
     for j in range(p_len):
         if transformer.mixer_kind(cfg, j) == "attn":
-            one = attention.make_paged_cache(cfg, num_blocks + 1,
-                                             block_size)
-        else:
-            one = transformer.make_block_state(cfg, j, batch, max_len)
-        stacked = jax.tree_util.tree_map(
+            out.append(attention.make_paged_cache(cfg, n_groups,
+                                                  num_blocks + 1,
+                                                  block_size))
+            continue
+        one = transformer.make_block_state(cfg, j, batch, max_len)
+        out.append(jax.tree_util.tree_map(
             lambda a: jnp.broadcast_to(a, (n_groups,) + a.shape).copy()
-            if a.size else a, one)
-        out.append(stacked)
+            if a.size else a, one))
     return out
 
 
@@ -262,38 +264,66 @@ def forward(params: Params, tokens: jax.Array, cfg: ModelConfig, *,
     p_len = transformer.period(cfg)
     aux_total: dict[str, jax.Array] = {}
 
-    def group_body(x, group_in):
-        """One group = one period of distinct blocks."""
-        blk_params, blk_states = group_in
+    def group_body(x, layer, pools, blk_params, blk_states):
+        """One group = one period of distinct blocks.  ``pools[j]`` is
+        period position j's whole paged pool stack, read and written at
+        group ``layer`` (None where that position's state is a per-group
+        slice in ``blk_states``)."""
+        pools = list(pools)
         new_states = []
         aux_acc = {}
         for j in range(p_len):
-            st = blk_states[j] if blk_states is not None else None
-            if st is not None and not st:          # empty dict = stateless
-                st = None
+            if pools[j] is not None:
+                st = pools[j]
+            else:
+                st = blk_states[j] if blk_states is not None else None
+                if st is not None and not st:      # empty dict = stateless
+                    st = None
             with jax.named_scope(f"layer{j}"):
                 x, st_new, aux = transformer.apply_block(
                     blk_params[j], x, cfg, j, positions=positions,
                     state=st, cache_index=cache_index,
                     encoder_out=encoder_out, block_table=block_table,
                     kv_len=kv_len, write_table=write_table,
-                    collect_states=collect_states)
+                    collect_states=collect_states, pool_layer=layer)
+            if pools[j] is not None:
+                pools[j], st_new = st_new, None
             new_states.append(st_new if st_new is not None else {})
             for k, v in aux.items():
                 aux_acc[k] = aux_acc.get(k, 0.0) + v
-        return x, (new_states, aux_acc)
+        return x, pools, new_states, aux_acc
+
+    # Paged pools stay whole through the layer loop: they ride in the
+    # carry and each group's attention reads and writes its own layer of
+    # them in place.  Every other state (recurrent rows, contiguous
+    # caches) is sliced per group as scan xs and restacked as ys.
+    no_pools = [None] * p_len
+    if states is None:
+        pools, sliced = no_pools, None
+    else:
+        pools = [st if attention.is_paged_cache(st) else None
+                 for st in states]
+        sliced = [{} if attention.is_paged_cache(st) else st
+                  for st in states]
 
     n_groups = cfg.num_layers // p_len
     if scan_layers:
         if states is None:
-            body = lambda x, bp: group_body(x, (bp, None))    # noqa: E731
+            def body(x, bp):
+                x, _, _, aux = group_body(x, None, no_pools, bp, None)
+                return x, aux
             if remat:
                 body = jax.checkpoint(body)
-            h, (_, aux_stack) = jax.lax.scan(body, h, params["blocks"])
+            h, aux_stack = jax.lax.scan(body, h, params["blocks"])
             out_states = None
         else:
-            h, (out_states, aux_stack) = jax.lax.scan(
-                group_body, h, (params["blocks"], states))
+            def body(carry, group_in):
+                x, layer, pools = carry
+                x, pools, new_states, aux = group_body(x, layer, pools,
+                                                       *group_in)
+                return (x, layer + 1, pools), (new_states, aux)
+            (h, _, pools), (out_states, aux_stack) = jax.lax.scan(
+                body, (h, jnp.int32(0), pools), (params["blocks"], sliced))
         if aux_stack:
             aux_total = {k: jnp.sum(v) for k, v in aux_stack.items()}
     else:
@@ -308,8 +338,8 @@ def forward(params: Params, tokens: jax.Array, cfg: ModelConfig, *,
                                         params["blocks"])
             st = None
             if states is not None:
-                st = jax.tree_util.tree_map(lambda l, g=g: l[g], states)
-            h, (new_st, aux_g) = body(h, (bp, st))
+                st = jax.tree_util.tree_map(lambda l, g=g: l[g], sliced)
+            h, pools, new_st, aux_g = body(h, g, pools, bp, st)
             collected.append(new_st)
             for k, v in aux_g.items():
                 aux_total[k] = aux_total.get(k, 0.0) + v
@@ -318,6 +348,9 @@ def forward(params: Params, tokens: jax.Array, cfg: ModelConfig, *,
                 lambda *ls: jnp.stack(ls), *collected)
         else:
             out_states = None
+    if out_states is not None:
+        out_states = [pool if pool is not None else st
+                      for pool, st in zip(pools, out_states)]
 
     h = layers.norm_apply(params["final_norm"], h, cfg)
     if last_only:

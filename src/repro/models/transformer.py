@@ -123,10 +123,12 @@ def apply_block(p: Params, x: jax.Array, cfg: ModelConfig, layer_idx: int, *,
                 kv_len: int | None = None,
                 write_table: jax.Array | None = None,
                 collect_states: bool = False,
+                pool_layer: jax.Array | int | None = None,
                 ) -> tuple[jax.Array, Params | None,
                            dict[str, jax.Array]]:
     """Returns (x, new_state, aux_losses).  ``block_table``/``kv_len``
-    select the paged KV path in self-attention (serve.kv_pool);
+    select the paged KV path in self-attention (serve.kv_pool), whose
+    state is then the whole stacked pool, addressed at ``pool_layer``;
     ``write_table`` re-routes its scatters (prefix-cache shared blocks
     are read-only).  ``collect_states``: recurrent mixers return their
     state after *every* position ([B, S, ...] leaves) instead of only
@@ -143,7 +145,7 @@ def apply_block(p: Params, x: jax.Array, cfg: ModelConfig, layer_idx: int, *,
             cache_index=cache_index,
             use_rope=not cfg.is_encoder_decoder,
             block_table=block_table, kv_len=kv_len,
-            write_table=write_table)
+            write_table=write_table, pool_layer=pool_layer)
     elif mk == "mamba":
         h, state = ssm.mamba(p["mamba"], h, cfg, state=state,
                              collect_states=collect_states)

@@ -6,9 +6,10 @@ the serving analogue is the KV cache.  The contiguous layout reserves a
 whole ``[max_len]`` window per decode slot, so one long request strands
 ``slots * max_len`` worth of storage however short its co-tenants are.
 Here the cache is a single pool of ``num_blocks`` fixed-size token
-blocks (``[num_blocks, block_size, kv_heads, head_dim]`` per layer
-group) and each request owns just the blocks its tokens actually touch,
-mapped through a per-slot *block table*.
+blocks (``[n_groups, num_blocks, block_size, kv_heads * head_dim]`` per
+attention period-position: the heads folded into the lanes) and each
+request owns just the blocks its tokens actually touch, mapped through
+a per-slot *block table*.
 
 Layout conventions
 ------------------
@@ -45,7 +46,7 @@ import jax.numpy as jnp
 
 from repro.config import ModelConfig
 from repro.models import transformer
-from repro.models.attention import paged_write_cells
+from repro.models.attention import is_paged_cache, paged_write_cells
 from repro.serve.errors import BlockNotLive, BlockOutOfRange
 
 TRASH_BLOCK = 0
@@ -406,10 +407,6 @@ def _mask_shared_cols(block_table: jax.Array,
 # states keep their per-slot rows
 # ---------------------------------------------------------------------------
 
-def is_paged_cache(state: Any) -> bool:
-    return isinstance(state, dict) and "k_pool" in state
-
-
 def slot_states_view(cfg: ModelConfig, states: list[Any],
                      slot: jax.Array) -> list[Any]:
     """A batch-1 view of ``slot`` for chunked prefill: recurrent leaves
@@ -494,13 +491,24 @@ def freeze_inactive_rows(states_old: list[Any], states_new: list[Any],
     return out
 
 
+def _every_layer(pool: jax.Array, phys: jax.Array, off: jax.Array
+                 ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Index arrays addressing cells ``(phys, off)`` ([B, S]) in every
+    layer of a stacked ``[G, NB, bs, lanes]`` pool: a gather or scatter
+    whose only window is the lane axis, which leaves the pool in the
+    layout the layer loop carries it in (a ``pool[:, phys, off]``
+    window spanning the layers makes XLA relayout the whole pool)."""
+    layers = jnp.arange(pool.shape[0], dtype=phys.dtype)[:, None, None]
+    return layers, phys[None], off[None]
+
+
 def spec_save_cells(states: list[Any], write_table: jax.Array,
                     cache_index: jax.Array, s: int) -> list[Any]:
     """Gather the pool cells a speculative verify step is about to
     overwrite (each row's next ``s`` positions through ``write_table``).
 
     Returns one entry per layer group: ``None`` for recurrent groups, a
-    ``{"k_pool", "v_pool"}`` dict of [n_groups, B, S, KV, hd] gathered
+    ``{"k_pool", "v_pool"}`` dict of [n_groups, B, S, KV * hd] gathered
     values for paged ones.  Together with :func:`spec_restore_cells`
     this makes draft writes transactional: after restore, the pool is
     bit-identical to one that only ever saw the accepted tokens."""
@@ -509,9 +517,10 @@ def spec_save_cells(states: list[Any], write_table: jax.Array,
         if not is_paged_cache(st):
             saved.append(None)
             continue
-        bs = st["k_pool"].shape[2]
-        phys, off = paged_write_cells(write_table, cache_index, s, bs)
-        saved.append({name: st[name][:, phys, off]
+        phys, off = paged_write_cells(write_table, cache_index, s,
+                                      st["k_pool"].shape[2])
+        cells = _every_layer(st["k_pool"], phys, off)
+        saved.append({name: st[name][cells]
                       for name in ("k_pool", "v_pool")})
     return saved
 
@@ -530,15 +539,16 @@ def spec_restore_cells(states: list[Any], saved: list[Any],
         if sv is None:
             out.append(st)
             continue
-        bs = st["k_pool"].shape[2]
-        phys, off = paged_write_cells(write_table, cache_index, s, bs)
+        phys, off = paged_write_cells(write_table, cache_index, s,
+                                      st["k_pool"].shape[2])
         committed = rel < advance[:, None]
         rphys = jnp.where(committed,
                           jnp.asarray(TRASH_BLOCK, phys.dtype), phys)
+        cells = _every_layer(st["k_pool"], rphys, off)
         st = dict(st)
         with jax.named_scope("spec_restore"):
             for name in ("k_pool", "v_pool"):
-                st[name] = st[name].at[:, rphys, off].set(sv[name])
+                st[name] = st[name].at[cells].set(sv[name])
         out.append(st)
     return out
 
@@ -631,7 +641,8 @@ def has_recurrent_state(cfg: ModelConfig) -> bool:
                for j in range(p_len))
 
 
-def place_serve_states(states: list[Any], mesh) -> list[Any]:
+def place_serve_states(states: list[Any], mesh, kv_heads: int
+                       ) -> list[Any]:
     """Place a freshly-initialised decode-state tree on a TP serving
     mesh: KV pools/caches shard their KV-head axis over ``model``
     (``dist.sharding.serve_state_specs``), recurrent rows replicate.
@@ -641,7 +652,7 @@ def place_serve_states(states: list[Any], mesh) -> list[Any]:
     ``shard_act`` each step, so per-token writes never drift it).
     """
     from repro.dist import sharding as shd
-    specs = shd.serve_state_specs(states, mesh)
+    specs = shd.serve_state_specs(states, mesh, kv_heads=kv_heads)
     return jax.device_put(states, shd.named_shardings(mesh, specs))
 
 

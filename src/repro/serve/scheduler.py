@@ -312,6 +312,37 @@ def make_slot_step(cfg: ModelConfig, kv_len: int | None = None):
     return slot_step
 
 
+def make_chunk_prefill(cfg: ModelConfig, max_len: int):
+    """Build the batch-1 chunk step: run ``tokens`` of one slot's prompt
+    against the shared tree — K/V scatter through the slot's block-table
+    row into the pool, recurrent rows sliced out / spliced back (they
+    are O(B * d), not O(B * max_len * d)).
+
+    (params, states, tokens [1,S], start, table_row [1,W], slot,
+     shared_cols [1]) -> (states', logits [1,1,V])
+
+    Compiles once per distinct chunk length: with chunked prefill that
+    is the block size plus ragged tails, not one shape per prompt
+    length."""
+
+    def chunk_prefill(params, states, tokens, start, table_row, slot,
+                      shared_cols):
+        # same read/write split as the decode step: the tail chunk of a
+        # prefix-cache hit must *attend* the shared K/V but its scatters
+        # must never land in a shared block
+        write_row = _mask_shared_cols(table_row, shared_cols)
+        one = kv_pool.slot_states_view(cfg, states, slot)
+        logits, one, _ = lm.forward(
+            params, tokens, cfg, states=one,
+            cache_index=jnp.reshape(start, (1,)),
+            block_table=table_row, last_only=True, kv_len=max_len,
+            write_table=write_row)
+        states = kv_pool.slot_states_merge(cfg, states, one, slot)
+        return states, logits
+
+    return chunk_prefill
+
+
 def make_spec_step(cfg: ModelConfig, k: int, kv_len: int):
     """Build the draft-and-verify speculative decode step (paged only).
 
@@ -532,7 +563,9 @@ class ContinuousBatchingScheduler:
                     make_spec_step(self.cfg, self.speculate_k,
                                    kv_len=max_len),
                     donate_argnums=_STEP_DONATE)
-            self._chunk_prefill = self._build_chunk_prefill()
+            self._chunk_prefill = jax.jit(
+                make_chunk_prefill(self.cfg, self.max_len),
+                donate_argnums=_STEP_DONATE)
             self._has_recurrent = kv_pool.has_recurrent_state(self.cfg)
             cfg_, ml_ = self.cfg, max_len
             self._reset_slot = jax.jit(
@@ -589,7 +622,8 @@ class ContinuousBatchingScheduler:
             self._prefills = {}
             self._prefix = None
         if self.mesh is not None:
-            self.states = kv_pool.place_serve_states(self.states, self.mesh)
+            self.states = kv_pool.place_serve_states(
+                self.states, self.mesh, self.cfg.num_kv_heads)
         # host mirrors of the per-slot lanes (tiny; re-shipped per step)
         self._cur_tok = np.zeros((b, 1), np.int32)
         self._cache_index = np.zeros((b,), np.int32)
@@ -635,33 +669,6 @@ class ContinuousBatchingScheduler:
             lambda f, o: jax.lax.dynamic_update_slice_in_dim(
                 f, o.astype(f.dtype), slot, axis=1),
             full_states, one_states)
-
-    def _build_chunk_prefill(self):
-        """The jitted batch-1 chunk step: run ``tokens`` of one slot's
-        prompt against the shared tree — K/V scatter through the slot's
-        block-table row into the pool, recurrent rows sliced out /
-        spliced back (they are O(B * d), not O(B * max_len * d)).
-        Compiles once per distinct chunk length: with chunked prefill
-        that is the block size plus ragged tails, not one shape per
-        prompt length."""
-        cfg, max_len = self.cfg, self.max_len
-
-        def chunk_prefill(params, states, tokens, start, table_row, slot,
-                          shared_cols):
-            # same read/write split as the decode step: the tail chunk
-            # of a prefix-cache hit must *attend* the shared K/V but its
-            # scatters must never land in a shared block
-            write_row = _mask_shared_cols(table_row, shared_cols)
-            one = kv_pool.slot_states_view(cfg, states, slot)
-            logits, one, _ = lm.forward(
-                params, tokens, cfg, states=one,
-                cache_index=jnp.reshape(start, (1,)),
-                block_table=table_row, last_only=True, kv_len=max_len,
-                write_table=write_row)
-            states = kv_pool.slot_states_merge(cfg, states, one, slot)
-            return states, logits
-
-        return jax.jit(chunk_prefill, donate_argnums=_STEP_DONATE)
 
     # -- admission ---------------------------------------------------------
 

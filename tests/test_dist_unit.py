@@ -212,16 +212,17 @@ def test_serve_state_specs_pool_and_cache_head_axis():
     mesh = make_test_mesh((2,), ("model",))
     cfg = small_test_config(num_kv_heads=4)
     paged = lm.init_paged_state(cfg, 2, 32, num_blocks=6, block_size=4)
-    specs = shd.serve_state_specs(paged, mesh)
-    assert specs[0]["k_pool"] == P(None, None, None, "model", None)
-    assert specs[0]["v_pool"] == P(None, None, None, "model", None)
+    specs = shd.serve_state_specs(paged, mesh, kv_heads=4)
+    # [G, NB, bs, KV * hd]: the folded lanes split into whole heads
+    assert specs[0]["k_pool"] == P(None, None, None, "model")
+    assert specs[0]["v_pool"] == P(None, None, None, "model")
     contig = lm.init_state(cfg, 2, 32)
-    specs = shd.serve_state_specs(contig, mesh)
+    specs = shd.serve_state_specs(contig, mesh, kv_heads=4)
     assert specs[0]["k"] == P(None, None, None, "model", None)
     # recurrent rows replicate (no data axis on the 1-D serving mesh)
     cfg_x = small_test_config(num_kv_heads=4, xlstm_slstm_every=2)
     st = lm.init_state(cfg_x, 2, 32)
-    specs = shd.serve_state_specs(st, mesh)
+    specs = shd.serve_state_specs(st, mesh, kv_heads=4)
     # mlstm c is [G, B, heads, hd, hd]: fully replicated
     assert specs[1]["c"] == P(*([None] * st[1]["c"].ndim))
 
@@ -232,8 +233,10 @@ def test_serve_state_specs_indivisible_heads_drop():
     from repro.config import small_test_config
     cfg = small_test_config(num_kv_heads=3)   # 3 % 2 != 0
     paged = lm.init_paged_state(cfg, 2, 32, num_blocks=6, block_size=4)
-    specs = shd.serve_state_specs(paged, mesh)
-    assert specs[0]["k_pool"] == P(None, None, None, None, None)
+    # 3 heads of 16 make 48 lanes, which 2 divides: the guard goes by
+    # whole heads, not by the lane count
+    specs = shd.serve_state_specs(paged, mesh, kv_heads=3)
+    assert specs[0]["k_pool"] == P(None, None, None, None)
 
 
 def test_validate_tp_raises_on_indivisible():

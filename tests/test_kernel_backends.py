@@ -179,18 +179,21 @@ def test_gf2_mvm_backends_bit_identical(seed, m, k, n):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
 
-def _paged_case(rng, *, b, s, w, bs, kvh, g, hd, dtype=jnp.bfloat16):
+def _paged_case(rng, *, b, s, w, bs, kvh, g, hd, layers=2, layer=1,
+                dtype=jnp.bfloat16):
     """A scheduler-realistic paged-attention state: every *active* row's
     causally visible positions map to allocated (non-trash) blocks in
     both tables — the invariant the real block allocator maintains, and
     the boundary of the kernel's bit-identity guarantee (trash content
-    is not part of the contract; inactive rows are discarded)."""
+    is not part of the contract; inactive rows are discarded).  The
+    pools are the stacked lane-dense ``[layers, NB, bs, KV * hd]``
+    store, addressed at ``layer``."""
     nb = 1 + b * w                       # block 0 = trash
     q = jnp.asarray(rng.standard_normal((b, s, kvh, g, hd)), dtype)
     kn = jnp.asarray(rng.standard_normal((b, s, kvh, hd)), dtype)
     vn = jnp.asarray(rng.standard_normal((b, s, kvh, hd)), dtype)
-    kp = jnp.asarray(rng.standard_normal((nb, bs, kvh, hd)), dtype)
-    vp = jnp.asarray(rng.standard_normal((nb, bs, kvh, hd)), dtype)
+    kp = jnp.asarray(rng.standard_normal((layers, nb, bs, kvh * hd)), dtype)
+    vp = jnp.asarray(rng.standard_normal((layers, nb, bs, kvh * hd)), dtype)
     # disjoint per-row block ranges; depths keep every visible position
     # (and every write) inside the row's allocated columns
     table = np.arange(1, 1 + b * w).reshape(b, w)
@@ -202,7 +205,8 @@ def _paged_case(rng, *, b, s, w, bs, kvh, g, hd, dtype=jnp.bfloat16):
     if ci[0] >= bs:
         wtable[0, 0] = 0
     return (q, kn, vn, kp, vp, jnp.asarray(table, jnp.int32),
-            jnp.asarray(wtable, jnp.int32), jnp.asarray(ci, jnp.int32))
+            jnp.asarray(wtable, jnp.int32), jnp.asarray(ci, jnp.int32),
+            jnp.int32(layer))
 
 
 @given(seed=st.integers(0, 2**31 - 1),
@@ -225,11 +229,13 @@ def test_paged_attention_backends_bit_identical(seed, s, bs, geom,
                          backend="xla")
     kk = paged_attention(*args, kv_len=kv_len, softcap=softcap,
                          backend=KERNEL)
-    for got, ref in zip(kk, kx):
-        # pools: every real block identical (trash, id 0, is outside the
-        # contract); outputs: all rows are active here, all identical
-        np.testing.assert_array_equal(np.asarray(got)[1:],
-                                      np.asarray(ref)[1:])
+    # pools: every real block of every layer identical (trash, id 0, is
+    # outside the contract); outputs: all rows are active here, all
+    # identical
+    for got, ref in zip(kk[:2], kx[:2]):
+        np.testing.assert_array_equal(np.asarray(got)[:, 1:],
+                                      np.asarray(ref)[:, 1:])
+    np.testing.assert_array_equal(np.asarray(kk[2]), np.asarray(kx[2]))
 
 
 def test_paged_attention_ambient_backend_and_pool_update():
@@ -238,13 +244,59 @@ def test_paged_attention_ambient_backend_and_pool_update():
     with registry.use_backend(KERNEL):
         kp, vp, out = paged_attention(*args)
     ref = paged_attention(*args, backend="xla")
-    np.testing.assert_array_equal(np.asarray(kp)[1:], np.asarray(ref[0])[1:])
+    np.testing.assert_array_equal(np.asarray(kp)[:, 1:],
+                                  np.asarray(ref[0])[:, 1:])
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref[2]))
-    # the write actually landed: the pool changed at the written slot
-    ci, table = args[7], args[5]
+    # the write actually landed: the pool changed at the written slot of
+    # the addressed layer
+    ci, table, layer = args[7], args[5], int(args[8])
     b0_blk = int(table[0, int(ci[0]) // 4])
-    assert not np.array_equal(np.asarray(kp)[b0_blk],
-                              np.asarray(args[3])[b0_blk])
+    assert not np.array_equal(np.asarray(kp)[layer, b0_blk],
+                              np.asarray(args[3])[layer, b0_blk])
+
+
+@pytest.mark.parametrize("s", [1, 16])
+def test_paged_attention_addresses_one_layer_of_the_stack(s):
+    """The kernel called at layer 1 of a 3-layer stacked lane-dense pool:
+    that layer is bit-identical to ``ref.py``'s (trash block aside),
+    every other layer and every block no write lands in are
+    bit-identical to the input.  Row 0 attends a prefix-shared first
+    column (trash-routed in its write table); row 2's second column is
+    trash-routed while its writes cross it, so those tokens land in the
+    trash block and the row reads the column's cached K/V, as the
+    composition does."""
+    from repro.kernels.paged_attention import paged_attention_ref
+    b, w, bs, kvh, g, hd, layers, layer = 3, 4, 8, 2, 2, 64, 3, 1
+    rng = np.random.default_rng(16 + s)
+    q, kn, vn, kp, vp, table, _, _, _ = _paged_case(
+        rng, b=b, s=s, w=w, bs=bs, kvh=kvh, g=g, hd=hd, layers=layers,
+        layer=layer)
+    ci = np.asarray([bs + 4, 0, bs + 2] if s == 1 else [bs + 4, 16, 4])
+    wtable = np.asarray(table).copy()
+    wtable[0, 0] = 0                 # shared prefix: read-only
+    wtable[2, 1] = 0                 # written across, routed to trash
+    wtable = jnp.asarray(wtable, jnp.int32)
+    args = (q, kn, vn, kp, vp, table, wtable, jnp.asarray(ci, jnp.int32),
+            jnp.int32(layer))
+    got = paged_attention(*args, backend=KERNEL)
+    ref = paged_attention_ref(*args)
+    np.testing.assert_array_equal(np.asarray(got[2]), np.asarray(ref[2]))
+    written = {int(wtable[r, p // bs]) for r in range(b)
+               for p in range(ci[r], ci[r] + s)}
+    untouched = [blk for blk in range(1 + b * w) if blk not in written]
+    assert 0 in written and untouched
+    for new, want, old in zip(got[:2], ref[:2], (kp, vp)):
+        new, want, old = (np.asarray(a) for a in (new, want, old))
+        np.testing.assert_array_equal(new[layer, 1:], want[layer, 1:])
+        for other in range(layers):
+            if other != layer:
+                np.testing.assert_array_equal(new[other], old[other])
+        np.testing.assert_array_equal(new[layer, untouched],
+                                      old[layer, untouched])
+    # and the writes that were not trash-routed landed
+    blk = int(table[0, (bs + 4) // bs])
+    assert not np.array_equal(np.asarray(got[0])[layer, blk],
+                              np.asarray(kp)[layer, blk])
 
 
 # ---------------------------------------------------------------------------

@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.config import small_test_config
+from repro.config import MoEConfig, small_test_config
 from repro.models import attention, lm
 from repro.serve import kv_pool
 from repro.serve.errors import (BlockAllocatorError, BlockNotLive,
@@ -290,21 +290,22 @@ def test_paged_attention_decode_matches_contiguous(block_size):
 
     w = kv_pool.table_width(max_len, block_size)
     nb = b * w
-    pool = attention.make_paged_cache(cfg, nb + 1, block_size)
+    pool = attention.make_paged_cache(cfg, 1, nb + 1, block_size)
     # interleaved block assignment (slot i owns blocks i, i+b, ...) so a
     # row's logical positions are physically scattered
     table = np.zeros((b, w), np.int32)
     for i in range(b):
         table[i] = 1 + i + b * np.arange(w)
     # mirror the contiguous history into the pool through the table
+    # (the pool folds the KV heads into its lanes)
     kf = np.zeros(pool["k_pool"].shape, np.float32)
     vf = np.zeros(pool["v_pool"].shape, np.float32)
     hist_np = np.asarray(hist, np.float32)
     for i in range(b):
         for t in range(max_len):
             blk, off = table[i][t // block_size], t % block_size
-            kf[blk, off] = hist_np[i, t]
-            vf[blk, off] = hist_np[i, t] * 0.5
+            kf[0, blk, off] = hist_np[i, t].reshape(-1)
+            vf[0, blk, off] = hist_np[i, t].reshape(-1) * 0.5
     pool = {"k_pool": jnp.asarray(kf).astype(jnp.bfloat16),
             "v_pool": jnp.asarray(vf).astype(jnp.bfloat16)}
 
@@ -312,7 +313,7 @@ def test_paged_attention_decode_matches_contiguous(block_size):
         p, x, cfg, positions=positions, cache=cache, cache_index=index)
     out_p, cache_p = attention.attention(
         p, x, cfg, positions=positions, cache=pool, cache_index=index,
-        block_table=jnp.asarray(table), kv_len=max_len)
+        block_table=jnp.asarray(table), kv_len=max_len, pool_layer=0)
     np.testing.assert_array_equal(np.asarray(out_c, np.float32),
                                   np.asarray(out_p, np.float32))
 
@@ -322,7 +323,50 @@ def test_paged_attention_decode_matches_contiguous(block_size):
     for i in range(b):
         t = int(index[i])
         blk, off = table[i][t // block_size], t % block_size
-        np.testing.assert_array_equal(kc[i, t], kp[blk, off])
+        np.testing.assert_array_equal(kc[i, t].reshape(-1), kp[0, blk, off])
+
+
+@pytest.mark.parametrize("scan_layers", [True, False])
+def test_paged_pools_carried_through_the_layer_loop(scan_layers):
+    """A period with two attention positions (dense and MoE FFNs
+    alternating: 2 groups of 2): each position's stacked pool rides
+    whole through the layer loop, scanned or unrolled, and group g
+    writes layer g of it.  Logits are bit-identical to the contiguous
+    cache, and every written cell holds the contiguous cache's K/V at
+    the same (layer, row, position)."""
+    cfg = small_test_config(num_layers=4, moe_layer_period=2,
+                            moe=MoEConfig(num_experts=4, top_k=2))
+    b, s, max_len, bs = 2, 3, 16, 4
+    params = lm.init_params(cfg, jax.random.PRNGKey(0))
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (b, s), 0,
+                                cfg.vocab_size)
+    index = jnp.asarray([0, 5], jnp.int32)
+    w = kv_pool.table_width(max_len, bs)
+    table = 1 + np.arange(b * w, dtype=np.int32).reshape(b, w)
+    paged = lm.init_paged_state(cfg, b, max_len, num_blocks=b * w,
+                                block_size=bs)
+    assert [kv_pool.is_paged_cache(st) for st in paged] == [True, True]
+    logits_c, st_c, _ = lm.forward(
+        params, tokens, cfg, states=lm.init_state(cfg, b, max_len),
+        cache_index=index, scan_layers=scan_layers)
+    logits_p, st_p, _ = lm.forward(
+        params, tokens, cfg, states=paged, cache_index=index,
+        block_table=jnp.asarray(table), kv_len=max_len,
+        scan_layers=scan_layers)
+    np.testing.assert_array_equal(np.asarray(logits_c, np.float32),
+                                  np.asarray(logits_p, np.float32))
+    for cont, pool in zip(st_c, st_p):
+        assert pool["k_pool"].shape == (2, b * w + 1, bs, 2 * 16)
+        for name in ("k", "v"):
+            c = np.asarray(cont[name], np.float32)
+            p = np.asarray(pool[f"{name}_pool"], np.float32)
+            for g in range(2):
+                for i in range(b):
+                    for t in range(int(index[i]), int(index[i]) + s):
+                        blk, off = table[i, t // bs], t % bs
+                        np.testing.assert_array_equal(
+                            c[g, i, t].reshape(-1), p[g, blk, off])
+                        assert np.abs(p[g, blk, off]).sum() > 0
 
 
 def test_paged_state_memory_footprint():
@@ -347,7 +391,7 @@ def test_trash_block_isolation():
     in the trash block and never alias a live block."""
     cfg = small_test_config()
     block_size, w = 4, 4
-    pool = attention.make_paged_cache(cfg, 6, block_size)
+    pool = attention.make_paged_cache(cfg, 1, 6, block_size)
     p = attention.init_attention(jax.random.PRNGKey(0), cfg)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 1, cfg.d_model),
                           jnp.float32).astype(jnp.bfloat16)
@@ -356,8 +400,8 @@ def test_trash_block_isolation():
     index = jnp.asarray([6, 9], jnp.int32)
     _, cache = attention.attention(
         p, x, cfg, positions=index[:, None], cache=pool,
-        cache_index=index, block_table=table, kv_len=16)
-    kp = np.asarray(cache["k_pool"], np.float32)
+        cache_index=index, block_table=table, kv_len=16, pool_layer=0)
+    kp = np.asarray(cache["k_pool"], np.float32)[0]
     # row 0's write: position 6 -> table column 1 -> block 2, offset 2
     assert np.abs(kp[2, 2]).sum() > 0
     # row 1's write went to trash block 0; block 5 untouched

@@ -155,8 +155,8 @@ def test_tp_params_actually_sharded():
     wd = sched.params["blocks"][0]["mlp"]["wd"]["w"].wq
     assert wd.sharding.shard_shape(wd.shape)[-2] == wd.shape[-2] // 2
     pool = sched.states[0]["k_pool"]
-    assert pool.sharding.shard_shape(pool.shape)[-2] == \
-        pool.shape[-2] // 2                              # KV-head axis
+    assert pool.sharding.shard_shape(pool.shape)[-1] == \
+        pool.shape[-1] // 2                              # folded KV heads
 
 
 def test_tp_row_sharded_pum_linear_psum_is_exact():
