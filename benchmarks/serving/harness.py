@@ -2,10 +2,16 @@
 
 A cell names a configuration (``configs/<name>.json``) and a traffic mix
 (``traffic/<name>.json``); its metrics are the readers
-``metrics/<metric>.py`` that ``BENCHMARK.json`` assigns to it, the work
+``metrics/<metric>.py`` that ``BENCHMARK.json`` assigns to it (a metric
+split by the end-to-end metric it moves, ``<metric>.<part>``, reads with
+``metrics/<metric>.py`` unless it has a file of its own), the work
 of each kernel is ``kernel_work/<kernel>.py``, and the limits of its
-output check are ``limits/<cell>.json``.  Nothing here knows a cell, a
-model, a kernel or a metric by name.
+output check are ``limits/<cell>.json``.  The configuration names its
+plain reference, ``references/<name>.py``, which declares the served
+leaves it reads at their stacked shapes (``served_leaves``), each model
+layer (``layers``, a list of ``layout.Layer``) and, optionally, weight
+recipes for leaf kinds ``weights`` lacks (``RECIPES``).  Nothing here
+knows a cell, a model, a kernel or a metric by name.
 
 The program is reached through ``repro.launch.serve`` (configuration,
 scheduler) and ``repro.models.lm`` (the served tree's shapes and its
@@ -75,6 +81,8 @@ class Bench:
 
     def module(self, kind: str, name: str):
         path = self.dir / kind / f"{name}.py"
+        if kind == "metrics" and not path.exists():
+            path = self.dir / kind / f"{name.split('.')[0]}.py"
         mod_name = "bench_" + "".join(
             c if c.isalnum() else "_" for c in f"{kind}_{name}")
         spec = importlib.util.spec_from_file_location(mod_name, path)
@@ -161,6 +169,7 @@ class Window:
     model: dict
     peaks: dict | None
     kernel_work: dict
+    layers: list                       # the reference's, one per layer
     trace: trace.Reduced | None = None
 
     @property
@@ -196,7 +205,7 @@ class Window:
         work = self.kernel_work[kernel]
         total = 0.0
         for t in ticks:
-            w = work.work(t.rows, self.model)
+            w = work.work(t.rows, self.layers)
             total += max(w["ops"] / self.peaks[w["ops_peak"]],
                          w["bytes"] / self.peaks["hbm_bytes_per_s"])
         return total
@@ -414,7 +423,8 @@ def program_args(config: dict, mix: dict, seed: int, backend: str | None):
 
 def check_config(cfg, config: dict, shapes, reference) -> None:
     """The program runs what the configuration file states, and its
-    parameter tree holds every leaf the reference regenerates."""
+    parameter tree holds every leaf the reference declares
+    (``served_leaves``), each at the declared stacked shape."""
     bad = {k: (config[k], getattr(cfg, f)) for k, f in PROGRAM_KEYS.items()
            if k in config and config[k] != getattr(cfg, f)}
     if config.get("head_dim", cfg.resolved_head_dim) != cfg.resolved_head_dim:
@@ -427,23 +437,22 @@ def check_config(cfg, config: dict, shapes, reference) -> None:
                          f"file (file, program): {bad}")
     flat = {jax.tree_util.keystr(kp): sds.shape for kp, sds in
             jax.tree_util.tree_flatten_with_path(shapes)[0]}
-    layers = config["num_hidden_layers"]
-    for name, shape in reference.leaf_shapes(config).items():
-        path = reference.LAYER[name]
-        if flat.get(path) != (layers,) + tuple(shape):
+    for path, shape in reference.served_leaves(config).items():
+        if flat.get(path) != tuple(shape):
             raise ValueError(f"served tree has {path} {flat.get(path)}, "
-                             f"the reference expects "
-                             f"{(layers,) + tuple(shape)}")
+                             f"the reference expects {tuple(shape)}")
 
 
-def make_weights(cfg, seed: int):
+def make_weights(cfg, seed: int, recipes=None):
     """The served tree from ``--seed``, made and packed on the device in
-    one program; and the base keys the reference regenerates it from."""
+    one program; and the base keys the reference regenerates it from.
+    ``recipes`` are the reference's own, for leaf kinds ``weights``
+    lacks."""
     from repro.models import lm
     shapes = lm.params_shape(cfg)
     keys = weights.tree_keys(seed, shapes)
     params = jax.jit(lambda k: lm.prepack_for_serving(
-        weights.make_tree(k, shapes), cfg))(keys)
+        weights.make_tree(k, shapes, recipes), cfg))(keys)
     return jax.block_until_ready(params), keys, shapes
 
 
@@ -482,6 +491,7 @@ class Cell:
         self.limits = bench.json("limits", name)
         self.reference = bench.module(
             "references", self.config["serving"]["reference"])
+        self.layers = self.reference.layers(self.config)
         self.kernel_work = {k: bench.module("kernel_work", k)
                             for k in self.config["serving"]["kernels"]}
         self.backend = backend
@@ -511,7 +521,8 @@ class Cell:
         config, mix = self.config, self.mix
         args = program_args(config, mix, seed, self.backend)
         cfg = serve.served_config(args)
-        params, keys, shapes = make_weights(cfg, seed)
+        params, keys, shapes = make_weights(
+            cfg, seed, getattr(self.reference, "RECIPES", None))
         check_config(cfg, config, shapes, self.reference)
         after_weights = self.counter.snapshot()
         t_weights = time.perf_counter()
@@ -609,7 +620,8 @@ def run(root: pathlib.Path, cell: str, seed: int, seconds: float,
     win = Window(t0=t0, seconds=seconds, setup_s=start - t_start,
                  closed_at=closed_at, recs=recs, ticks=ticks,
                  compiles=window_compiles, slots=c.mix["slots"],
-                 model=c.config, peaks=c.peaks, kernel_work=c.kernel_work)
+                 model=c.config, peaks=c.peaks, kernel_work=c.kernel_work,
+                 layers=c.layers)
     result: dict = {}
     if traced:
         files = list(pathlib.Path(trace_dir).rglob("*.xplane.pb"))

@@ -16,6 +16,10 @@ whole sequences at once (no cache, no paging, no batching of unrelated
 rows into one step), one layer at a time so that it fits beside nothing
 else.  It imports nothing of the program: weights are regenerated from
 the seed by ``benchmarks.serving.weights``.
+
+It declares what it reads of the served tree (``served_leaves``), which
+the harness compares with the program's tree leaf by leaf, and each of
+its layers (``layers``), from which the work counts are taken.
 """
 from __future__ import annotations
 
@@ -26,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.serving import weights
+from benchmarks.serving.layout import Attention, Layer
 
 # where the served parameter tree keeps each weight (one layer group)
 LAYER = {
@@ -67,6 +72,25 @@ def leaf_shapes(model: dict) -> dict[str, tuple[int, ...]]:
     if model.get("attention_bias"):
         out.update(bq=(qd,), bk=(kvd,), bv=(kvd,))
     return out
+
+
+def served_leaves(model: dict) -> dict[str, tuple[int, ...]]:
+    """Every served leaf the reference reads, at its stacked shape: one
+    layer group, every layer alike."""
+    n = model["num_hidden_layers"]
+    return {LAYER[name]: (n,) + shape
+            for name, shape in leaf_shapes(model).items()}
+
+
+def layers(model: dict) -> list[Layer]:
+    """Every layer: Q, K, V, O, gate, up and down through the int8 MVM,
+    and grouped-query attention."""
+    s = sizes(model)
+    d, f, qd, kvd = s["d"], s["f"], s["h"] * s["hd"], s["kv"] * s["hd"]
+    layer = Layer(linears=((d, qd), (d, kvd), (d, kvd), (qd, d),
+                           (d, f), (d, f), (f, d)),
+                  attention=Attention(s["h"], s["kv"], s["hd"]))
+    return [layer] * s["layers"]
 
 
 def _quant(x, bits: int, axis: int):

@@ -51,7 +51,8 @@ def main(argv: list[str] | None = None) -> int:
         w = harness.Window(t0=t0, seconds=args.seconds, setup_s=0.0,
                            closed_at=time.perf_counter(), recs=recs,
                            ticks=ticks, compiles=0, slots=mix["slots"],
-                           model=cell.config, peaks=None, kernel_work={})
+                           model=cell.config, peaks=None, kernel_work={},
+                           layers=cell.layers)
         due = w.due()
         half = t0 + args.seconds / 2
         late = [r for r in due if r.due >= half]

@@ -52,6 +52,9 @@ def root(tmp_path_factory):
     spec["workloads"].append({"name": CELL, "config": "tiny-qwen",
                               "traffic": "tiny", "chips": 1,
                               "why": "a test"})
+    for m in spec["end_to_end"]:
+        if m["name"] == "itl_p95_ms":
+            m["workloads"].append(CELL)
     (root / "BENCHMARK.json").write_text(json.dumps(spec))
     (d / "configs" / "tiny-qwen.json").write_text(json.dumps(TINY_CONFIG))
     (d / "traffic" / "tiny.json").write_text(json.dumps(TINY_MIX))

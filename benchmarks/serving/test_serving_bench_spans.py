@@ -62,7 +62,8 @@ def test_host_gap_counts_from_each_read_to_the_next_dispatch():
 def test_host_gap_leaves_out_ticks_untraced_or_without_records():
     gap = _reader("host_gap_ms").gap_ms
     untraced = TICKS[:2] + [_tick(21, 30, traced=False)]
-    w = harness.Window(0.0, 1.0, 0.0, 1.0, [], untraced, 0, 1, {}, None, {})
+    w = harness.Window(0.0, 1.0, 0.0, 1.0, [], untraced, 0, 1, {}, None, {},
+                       [])
     assert gap(w.traced_ticks(), RECORDS) == pytest.approx(4.0)
     # a traced tick with no records between: the state before the
     # third tick's first dispatch is unknown
@@ -96,7 +97,8 @@ def test_prefill_tokens_per_dispatch_reads_the_chunk_records():
 def test_a_program_without_spans_reads_nothing(metric, monkeypatch):
     import repro.serve
     from repro.serve import spans
-    w = harness.Window(0.0, 1.0, 0.0, 1.0, [], TICKS, 0, 1, {}, None, {})
+    w = harness.Window(0.0, 1.0, 0.0, 1.0, [], TICKS, 0, 1, {}, None, {},
+                       [])
     monkeypatch.setattr(spans, "recorded", lambda: list(RECORDS))
     assert _reader(metric).read(w) is not None
     monkeypatch.delattr(repro.serve, "spans")

@@ -81,7 +81,7 @@ def _window(token_times, due=0.0, seconds=10.0):
     return harness.Window(t0=0.0, seconds=seconds, setup_s=1.0,
                           closed_at=seconds, recs=recs, ticks=[],
                           compiles=0, slots=4, model={}, peaks=None,
-                          kernel_work={})
+                          kernel_work={}, layers=[])
 
 
 def _metric(name, w):
@@ -134,6 +134,7 @@ def _ticked(modelled: bool):
     w.ticks = [harness.Tick(0.1, 0.2, rows, 4, 0.5, True),
                harness.Tick(0.2, 0.3, rows, 4, 0.5, True, modelled)]
     w.model = {**MODEL, "padded_vocab_size": 32}
+    w.layers = LAYERS
     w.peaks = {"int8_ops": 1e12, "bf16_flops": 1e12,
                "hbm_bytes_per_s": 1e9}
     w.kernel_work = {k: harness.Bench(REPO).module("kernel_work", k)
@@ -158,29 +159,30 @@ def test_work_metrics_go_unread_where_the_chunk_model_misses(metric):
 MODEL = {"hidden_size": 8, "intermediate_size": 16, "num_hidden_layers": 2,
          "num_attention_heads": 2, "num_key_value_heads": 1,
          "padded_vocab_size": 32}
+LAYERS = harness.Bench(REPO).module("references", "dense_gqa").layers(MODEL)
 
 
 def test_mvm_work_reads_weights_once_per_tick():
     work = harness.Bench(REPO).module("kernel_work", "bitslice_mvm").work
     kn = 8 * 8 + 2 * 8 * 4 + 8 * 8 + 3 * 8 * 16     # q, k, v, o, g, u, d
-    one = work([(1, 5, True)], MODEL)
-    four = work([(1, 5, True), (3, 9, False)], MODEL)
+    one = work([(1, 5, True)], LAYERS)
+    four = work([(1, 5, True), (3, 9, False)], LAYERS)
     assert one["ops"] == 2 * 1 * kn * 2
     assert four["ops"] == 2 * 4 * kn * 2
     # int8 in, int32 out: q and o, k and v, gate and up, down
     per_token = 2 * (8 + 32) + 2 * (8 + 16) + 2 * (8 + 64) + (16 + 32)
     assert one["bytes"] == 2 * (kn + per_token)
     assert four["bytes"] == 2 * (kn + 4 * per_token)
-    assert work([], MODEL)["bytes"] == 0
+    assert work([], LAYERS)["bytes"] == 0
 
 
 def test_attention_work_is_causal_over_live_context():
     work = harness.Bench(REPO).module("kernel_work",
                                       "paged_attention").work
-    decode = work([(1, 10, True)], MODEL)
+    decode = work([(1, 10, True)], LAYERS)
     assert decode["ops"] == 4 * 2 * 4 * 10 * 2
     assert decode["bytes"] == 2 * (2 * 10 * 1 * 4 + 2 * 1 * 2 * 4) * 2
-    chunk = work([(4, 20, False)], MODEL)
+    chunk = work([(4, 20, False)], LAYERS)
     assert chunk["ops"] == 4 * 2 * 4 * (4 * 16 + 10) * 2
 
 
@@ -194,6 +196,9 @@ def _root_with_extras(tmp_path):
     spec["workloads"].append({"name": "new-model.new-mix",
                               "config": "new-model", "traffic": "new-mix",
                               "chips": 1, "why": "a test"})
+    for m in spec["end_to_end"]:
+        if m["name"] == "itl_p95_ms":
+            m["workloads"].append("new-model.new-mix")
     spec["per_layer"].append({"name": "new_metric", "unit": "count",
                               "better": "lower", "source": "host_clock",
                               "layer": "harness", "moves": "itl_p50_ms",
@@ -217,6 +222,25 @@ def test_new_config_traffic_and_metric_files_are_found(tmp_path):
     assert bench.module("metrics", "new_metric").read(None) == 42
     e2e = [m["name"] for m in bench.metrics("new-model.new-mix", False)]
     assert e2e == ["setup_s", "itl_p95_ms"]
+
+
+def test_every_per_layer_metric_moves_a_metric_its_cells_report():
+    bench = harness.Bench(REPO)
+    for w in bench.spec["workloads"]:
+        e2e = {m["name"] for m in bench.metrics(w["name"], False)}
+        assert "setup_s" in e2e and len(e2e) >= 2
+        per_layer = bench.metrics(w["name"], True)
+        assert per_layer
+        for m in per_layer:
+            assert m["moves"] in e2e, (w["name"], m["name"])
+
+
+def test_a_split_metric_reads_with_its_base_reader_unless_it_has_its_own():
+    bench = harness.Bench(REPO)
+    split = bench.module("metrics", "step_mfu.open")
+    assert pathlib.Path(split.__file__).name == "step_mfu.py"
+    own = bench.module("metrics", "decode_occupancy.open")
+    assert pathlib.Path(own.__file__).name == "decode_occupancy.open.py"
 
 
 def test_every_metric_and_kernel_named_has_its_file():
